@@ -5,14 +5,15 @@ fleet (16 servers/cell -> N = 1024 edge + 1 cloud column) through
 ``core.mesh_router.route_batch_sharded`` on a D-device ``cells`` mesh,
 for D in {1, 2, 4, 8}, and records requests/sec per device count.
 
-XLA fixes the host device count at first jax init, so the sweep runs in
-ONE child process spawned under
+On an accelerator the sweep runs inline, in this one process, over the
+device counts the host has (a four-chip host sweeps D in {1, 2, 4}): a
+chip belongs to one process, so no child is started there. On CPU, XLA
+fixes the host device count at first jax init, so the sweep runs in ONE
+child process spawned under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; the child
 prints a ``FLEET_RESULT {json}`` line per device count, the parent
 parses them, prints the CSV rows and rewrites
-``benchmarks/BENCH_fleet.json``. (When the current process already
-exposes enough devices — a real multi-device host — the sweep runs
-inline.)
+``benchmarks/BENCH_fleet.json``.
 
     PYTHONPATH=src python -m benchmarks.fleet_scale
 
@@ -165,10 +166,11 @@ def _spawn_child(n_cells, per_cell, batch, devices, chunk, repeats, parity):
             if line.startswith(_RESULT_TAG)]
 
 
-def write_json(rows):
+def write_json(rows, platform, kind):
     base = rows[0]["req_per_s"]
     payload = {
         "benchmark": "mesh-sharded fleet routing (core.mesh_router)",
+        "device": {"platform": platform, "kind": kind},
         "shape": {
             "cells": rows[0]["cells"],
             "edge_servers": rows[0]["edge_servers"],
@@ -182,10 +184,12 @@ def write_json(rows):
         "speedup_vs_1_device": {
             str(r["devices"]): round(r["req_per_s"] / base, 3) for r in rows
         },
-        "note": ("forced host devices share one CPU's cores: the curve "
-                 "bounds sharding overhead, it is not an accelerator "
-                 "scaling claim; device-count invariance (bitwise "
-                 "choices) is asserted in the same run"),
+        "note": ("device-count invariance (bitwise choices) is asserted "
+                 "in the same run"
+                 + ("; forced host devices share one CPU's cores: the "
+                    "curve bounds sharding overhead, it is not an "
+                    "accelerator scaling claim" if platform == "cpu"
+                    else "")),
     }
     JSON_PATH.write_text(json.dumps(payload, indent=2) + "\n")
     return payload
@@ -202,6 +206,8 @@ def main(header=True, smoke=False, emit_json=True):
 
     import jax
 
+    if jax.default_backend() != "cpu":
+        devices = tuple(d for d in devices if d <= jax.device_count())
     if jax.device_count() >= max(devices):
         import contextlib
         import io
@@ -227,7 +233,7 @@ def main(header=True, smoke=False, emit_json=True):
     if smoke:
         print("fleet_scale_smoke,0.0,parity=bitwise_vs_plain_scan")
     if emit_json and rows:
-        write_json(rows)
+        write_json(rows, jax.default_backend(), jax.devices()[0].device_kind)
     return rows
 
 

@@ -20,6 +20,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _timeit(fn, *args, n=5, warmup=2):
     for _ in range(warmup):
@@ -291,6 +293,7 @@ def main(argv=None) -> None:
         sections = [(n, f) for n, f in SECTIONS if n in keep]
     else:
         sections = SECTIONS
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for _, fn in sections:
         if args.smoke and "smoke" in fn.__code__.co_varnames:

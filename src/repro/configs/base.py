@@ -50,7 +50,7 @@ class ArchConfig:
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
     remat: str = "full"         # none | full | dots
-    kernel_backend: str = "xla" # xla | pallas
+    kernel_backend: str = "xla" # xla | pallas | pallas-interpret
     moment_dtype: str = "float32"  # optimizer moments (bf16 for 100B+)
     grad_accum: int = 1         # microbatch gradient accumulation
     # §Perf hillclimb knobs (EXPERIMENTS.md):

@@ -378,7 +378,7 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
 
         return jax.vmap(one_cell)(sh)
 
-    routed = sharding.shard_map(
+    routed = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis),) * n_shard + (P(),) * len(repl),
         out_specs=(P(axis),) * 6, check_vma=False,
@@ -542,7 +542,7 @@ def _sharded_route_spill(params, state, model_b, prompt_b, gen_b, icell_b,
 
         return jax.vmap(one_bucket)(sh)
 
-    ch_o, lat_o, hit_o = sharding.shard_map(
+    ch_o, lat_o, hit_o = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis),) * n_shard + (P(),) * len(repl),
         out_specs=(P(axis),) * 3, check_vma=False,

@@ -28,9 +28,11 @@ def mlp_init(key, sizes: Sequence[int], final_scale: float = 1.0):
     return params
 
 
-def mlp_apply(params, x):
+def mlp_apply(params, x, precision=None):
+    """``precision`` is the matmul precision (``jax.lax.Precision``);
+    ``None`` takes the platform default, one bfloat16 pass on a TPU."""
     for i, layer in enumerate(params):
-        x = x @ layer["w"] + layer["b"]
+        x = jnp.matmul(x, layer["w"], precision=precision) + layer["b"]
         if i < len(params) - 1:
             x = jax.nn.relu(x)
     return x

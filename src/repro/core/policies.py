@@ -225,6 +225,13 @@ def cell_index_map(spec: ObsSpec, fleet_cell) -> tuple[np.ndarray, np.ndarray]:
     return rows.astype(np.int32), cell[rows]
 
 
+#: Matmul precision of the served actor MLP. The TPU's default for
+#: float32 matmuls is one bfloat16 pass, which keeps about three decimal
+#: digits of the logits, so argmax decisions could differ from the CPU
+#: oracle's; full float32 keeps the chip's choices equal to the CPU's.
+SERVE_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def _agent_slice(stacked, agent: int):
     """One agent's MLP from the stacked (leading-axis) actor pytree."""
     return jax.tree.map(lambda x: jnp.asarray(x)[agent], stacked)
@@ -287,7 +294,7 @@ def make_actor_policy(actor_params, spec: ObsSpec, fleet_params, *,
             ed_pos=dflt.ed_pos, es_pos=dflt.es_pos, cc_pos=dflt.cc_pos,
             f_ed=dflt.f_ed,
         )
-        out = networks.mlp_apply(mlp, o)
+        out = networks.mlp_apply(mlp, o, precision=SERVE_PRECISION)
         # head layout: [target logits (N+1) | eta | beta]; slot 0 is
         # "compute locally", which a routed request cannot do — serving
         # always places the request on the best ES head
@@ -349,7 +356,7 @@ def make_actor_policy(actor_params, spec: ObsSpec, fleet_params, *,
         # fusion — fused, XLA lowers the contraction as a loop nest
         # instead of one gemm call (measured ~4x slower end to end)
         rows = _fusion_barrier(_obs_rows(cctx, idx, compat))
-        out = networks.mlp_apply(mlp, rows)
+        out = networks.mlp_apply(mlp, rows, precision=SERVE_PRECISION)
         target = jnp.argmax(out[..., 1: n_ess + 1], axis=-1)  # (c, V)
         choice = jnp.take_along_axis(idx, target, axis=1)    # (c, V)
         # idx/cell_ok ride along so the per-step resolve skips the
@@ -433,7 +440,8 @@ def actor_action_columns(actor_params, spec: ObsSpec, fleet_params, state,
         reqs.gen_tokens * flops_tok / reqs.prompt_bits,
         jnp.asarray(fleet_params.flops_per_s)[idx], compat,
     )
-    out = networks.mlp_apply(mlp, obs)                       # (B, N+3)
+    out = networks.mlp_apply(mlp, obs,
+                             precision=SERVE_PRECISION)  # (B, N+3)
     eta = jax.nn.sigmoid(out[..., spec.num_ess + 1])
     beta = jax.nn.sigmoid(out[..., spec.num_ess + 2]) > 0.5
     if not model_aware:  # download action forced off, as in training

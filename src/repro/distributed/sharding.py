@@ -21,23 +21,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 
-# --- version compat ----------------------------------------------------------
-# ``jax.sharding.AxisType`` / ``jax.shard_map`` / ``make_mesh(axis_types=...)``
-# only exist on newer JAX releases; these wrappers pin ONE spelling for the
-# whole repo so every mesh/shard_map construction site works on either side.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{'axis_types': (AxisType.Auto,) * n}`` where the installed JAX has
-    ``AxisType`` (>= 0.6), else ``{}`` (Auto is the only behaviour there)."""
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
-
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
+    """``jax.make_mesh`` with explicit-Auto axis types.
 
     Unlike raw ``jax.make_mesh`` — which silently builds the mesh over a
     SUBSET of the platform's devices whenever ``prod(axis_shapes)`` is
@@ -61,27 +47,11 @@ def make_mesh(axis_shapes, axis_names, *, devices=None):
             f"but {source} {len(avail)}; pass an explicit subset "
             "(devices=jax.devices()[:n]) to build a smaller mesh"
         )
-    kwargs = {} if devices is None else {"devices": devices}
-    kwargs.update(axis_types_kwargs(len(axis_names)))
-    try:
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    except TypeError:  # installed make_mesh predates the axis_types kwarg
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` on new JAX; ``jax.experimental.shard_map`` (with
-    ``check_vma`` translated to its old name ``check_rep``) on old JAX."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 def batch_axes(mesh):

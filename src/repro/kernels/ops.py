@@ -1,9 +1,13 @@
-"""Jit'd dispatch layer: every hot-spot op routes to either the Pallas TPU
-kernel (``backend="pallas"``, validated in interpret mode on CPU) or the
-memory-sane XLA implementation (``backend="xla"``, used by the CPU
-dry-run — Pallas TPU kernels cannot lower on the host platform).
+"""Jit'd dispatch layer: every hot-spot op routes to the Pallas TPU
+kernel or to the memory-sane XLA implementation, by ``backend``:
 
-Both backends share the oracles in ``ref.py``; tests assert allclose.
+* ``"pallas"`` compiles the kernel for the accelerator. It never falls
+  back to emulation: on a CPU backend the Pallas lowering raises.
+* ``"pallas-interpret"`` runs the same kernel through the Pallas
+  interpreter — the CPU test path, never a timing target.
+* ``"xla"`` is the reference implementation in ``ref.py``.
+
+All backends share the oracles in ``ref.py``; tests assert allclose.
 """
 from __future__ import annotations
 
@@ -11,40 +15,44 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 
-_INTERPRET = True  # this container is CPU-only; on TPU set False
+_PALLAS = ("pallas", "pallas-interpret")
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-6, backend: str = "xla"):
-    if backend == "pallas":
+    if backend in _PALLAS:
         from repro.kernels import rmsnorm as _k
 
-        return _k.rmsnorm(x, scale, eps=eps, interpret=_INTERPRET)
+        return _k.rmsnorm(x, scale, eps=eps,
+                          interpret=backend == "pallas-interpret")
     return ref.rmsnorm_naive(x, scale, eps)
 
 
 def attention(q, k, v, *, causal=True, window=0, q_offset=0, backend: str = "xla"):
-    if backend == "pallas":
+    if backend in _PALLAS:
         from repro.kernels import flash_attention as _k
 
         return _k.flash_attention(
-            q, k, v, causal, window, q_offset, 128, 128, _INTERPRET
+            q, k, v, causal, window, q_offset, 128, 128,
+            backend == "pallas-interpret",
         )
     return ref.attention_xla(q, k, v, causal=causal, window=window, q_offset=q_offset)
 
 
 def decode_attention(q, k, v, pos, *, window=0, backend: str = "xla"):
-    if backend == "pallas":
+    if backend in _PALLAS:
         from repro.kernels import flash_decode as _k
 
-        return _k.flash_decode(q, k, v, pos, window=window, interpret=_INTERPRET)
+        return _k.flash_decode(q, k, v, pos, window=window,
+                               interpret=backend == "pallas-interpret")
     return ref.decode_attention_naive(q, k, v, pos, window=window)
 
 
 def ssd(x, dt, a_log, b, c, d_skip, *, chunk: int = 256, backend: str = "xla"):
-    if backend == "pallas":
+    if backend in _PALLAS:
         from repro.kernels import ssd_scan as _k
 
-        return _k.ssd(x, dt, a_log, b, c, d_skip, chunk, _INTERPRET)
+        return _k.ssd(x, dt, a_log, b, c, d_skip, chunk,
+                      backend == "pallas-interpret")
     return ref.ssd_chunked_xla(x, dt, a_log, b, c, d_skip, chunk=chunk)
 
 
@@ -63,15 +71,15 @@ def route_score(
 ):
     """Fused (B, N) eq. 11 routing-score matrix (see ``route_score.py``).
 
-    Backends: ``"xla"`` (reference contraction), ``"pallas"`` (TPU
-    kernel; interpreted when this host is CPU-only), and
-    ``"pallas-interpret"`` (force interpret mode — the value the
-    ``REPRO_ROUTER_BACKEND`` env knob uses on CPU CI). ``eta``/``beta``
+    Backends: ``"xla"`` (reference contraction), ``"pallas"`` (the
+    compiled TPU kernel; raises on a CPU backend) and
+    ``"pallas-interpret"`` (the kernel under the Pallas interpreter —
+    the value CPU tests use). ``eta``/``beta``
     are the eq. 16 partial-offload / download-refusal columns; both
     backends fold them through ``costs.apply_eta_beta`` so the
     transform (and its ``None`` bitwise no-op) is shared.
     """
-    if backend in ("pallas", "pallas-interpret"):
+    if backend in _PALLAS:
         from repro.kernels import route_score as _k
 
         return _k.route_score(
@@ -80,7 +88,7 @@ def route_score(
             queue_tokens=queue_tokens, resident=resident, model=model,
             req_cell=req_cell, srv_cell=srv_cell, spill=spill,
             eta=eta, beta=beta, cloud_cell=cloud_cell,
-            interpret=_INTERPRET or backend == "pallas-interpret",
+            interpret=backend == "pallas-interpret",
         )
     return ref.route_score_xla(
         prompt_bits, size_bits, flops_tok, work,

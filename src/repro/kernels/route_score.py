@@ -23,7 +23,9 @@ and sliced back; padded lanes never reach the caller. Math runs in fp32
 for fp32/bf16 inputs (output cast back) and in fp64 for fp64 inputs —
 the x64 oracle-equivalence tier runs the kernel too, and interpret mode
 (the only place fp64 occurs) supports it. ``interpret=True`` runs the
-kernel on CPU per the ``kernels/ops.py`` convention; the XLA reference
+kernel under the Pallas interpreter on CPU (``backend="pallas-interpret"``
+in ``kernels/ops.py``); ``interpret=False`` compiles it for the TPU and
+is what ``backend="pallas"`` always uses. The XLA reference
 lives in ``kernels/ref.route_score_xla`` (same arithmetic via
 ``core.costs.edge_score_matrix``) and the two are pinned allclose in
 ``tests/test_route_score_kernel.py``.

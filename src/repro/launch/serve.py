@@ -72,6 +72,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +82,11 @@ from repro.configs import get_arch, list_archs, reduced
 from repro.core import batch_router, policies
 from repro.core.catalog import build_catalog
 from repro.core.router import CLOUD_CELL, EdgeServer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.workloads import compile_scenario, get_scenario, list_scenarios
+
+EDGE_ARCHS = ["smollm_135m", "starcoder2_3b", "mamba2_2p7b", "musicgen_medium"]
 
 
 def make_fleet(n_servers: int, catalog, flops=197e12, slots=2, cell=0,
@@ -192,13 +196,25 @@ def validate_mesh_flag(mesh):
         )
 
 
-def serve(num_requests=32, n_servers=3, policy="greedy", execute=True, seed=0,
-          gen_tokens=8, n_cells=1, drain_rate=0.0, arrival_rate=None,
-          chunk=None, backend=None, scenario="steady", mesh=None):
-    validate_mesh_flag(mesh)
+class Window(NamedTuple):
+    """One routing window as ``serve`` builds it: the fleet, its array
+    snapshot and the request stream compiled from ``(scenario, seed)``."""
+
+    params: batch_router.FleetParams
+    state: batch_router.FleetState
+    reqs: batch_router.RequestBatch
+    #: legacy per-request synchronous drain; ``None`` under a drain rate
+    drain_tokens: Optional[float]
+    cloud_index: Optional[int]  # the last server, on multi-cell fleets
+    spec: object                # the ScenarioSpec the stream came from
+
+
+def make_window(num_requests=32, n_servers=3, *, seed=0, gen_tokens=8,
+                n_cells=1, drain_rate=0.0, arrival_rate=None,
+                scenario="steady") -> Window:
+    """Build the fleet and compile the request stream of one window."""
     # serve the edge-suitable (small) members of the catalogue
-    edge_archs = ["smollm_135m", "starcoder2_3b", "mamba2_2p7b", "musicgen_medium"]
-    catalog = build_catalog(edge_archs)
+    catalog = build_catalog(EDGE_ARCHS)
     multicell = n_cells > 1
     if multicell:
         fleet = make_multicell_fleet(n_cells, n_servers, catalog,
@@ -206,14 +222,6 @@ def serve(num_requests=32, n_servers=3, policy="greedy", execute=True, seed=0,
     else:
         fleet = make_fleet(n_servers, catalog, drain_rate=drain_rate)
     fleet_params, fleet_state = batch_router.fleet_from_servers(fleet, catalog)
-    policy = resolve_policy_flag(policy, fleet_params, sharded=mesh is not None)
-
-    # local reduced models actually generate tokens for routed requests
-    models = {}
-    if execute:
-        for e in catalog:
-            cfg = reduced(get_arch(e.name))
-            models[e.index] = (cfg, lm.init_params(jax.random.key(e.index), cfg))
 
     # the whole stream — arrival stamps, model popularity, cells, prompt
     # sizes — compiles from (ScenarioSpec, seed): reproducible end to end
@@ -224,30 +232,61 @@ def serve(num_requests=32, n_servers=3, policy="greedy", execute=True, seed=0,
         spec = spec._replace(gen_tokens=(gen_tokens, gen_tokens))
     reqs = compile_scenario(spec, seed=seed, num_models=len(catalog),
                             num_cells=n_cells)
+    # with drain_rate > 0 the queues decay by drain_rate * dt between
+    # arrivals; otherwise each routed request drains the fleet like the
+    # old per-request loop
+    drain_tokens = (
+        None if drain_rate > 0.0
+        else float(np.mean(np.asarray(reqs.gen_tokens))) * len(fleet)
+        / max(num_requests, 1)
+    )
+    return Window(fleet_params, fleet_state, reqs, drain_tokens,
+                  len(fleet) - 1 if multicell else None, spec)
 
-    # route the WHOLE batch (all cells) in one jitted call
-    # (sequential-commit scan). With drain_rate > 0 the queues decay by
-    # drain_rate * dt between arrivals; otherwise each routed request
-    # drains the fleet like the old per-request loop. Under --mesh the
-    # batch is ONE reconciliation window of the sharded router, which
-    # takes no per-request drain_tokens (docs/sharding.md) — drain only
-    # through drain_rate there.
-    t0 = time.time()
+
+def route_window(window: Window, policy="greedy", *, chunk=None,
+                 backend=None, mesh=None):
+    """Route the WHOLE window (all cells) in one jitted call; returns
+    ``(state, outcome)``. It runs where the window's arrays live.
+
+    Under ``mesh`` the window is ONE reconciliation window of the
+    sharded router, which takes no per-request drain_tokens
+    (docs/sharding.md) — drain only through drain_rate there."""
     if mesh is not None:
         from repro.core import mesh_router
 
-        fleet_state, out = mesh_router.route_batch_sharded(
-            fleet_params, fleet_state, reqs, num_devices=mesh,
+        return mesh_router.route_batch_sharded(
+            window.params, window.state, window.reqs, num_devices=mesh,
             policy=policy, chunk=chunk, backend=backend,
         )
-    else:
-        fleet_state, out = batch_router.route_batch(
-            fleet_params, fleet_state, reqs,
-            None if drain_rate > 0.0
-            else float(np.mean(np.asarray(reqs.gen_tokens))) * len(fleet)
-            / max(num_requests, 1),
-            policy=policy, chunk=chunk, backend=backend,
-        )
+    return batch_router.route_batch(
+        window.params, window.state, window.reqs, window.drain_tokens,
+        policy=policy, chunk=chunk, backend=backend,
+    )
+
+
+def serve(num_requests=32, n_servers=3, policy="greedy", execute=True, seed=0,
+          gen_tokens=8, n_cells=1, drain_rate=0.0, arrival_rate=None,
+          chunk=None, backend=None, scenario="steady", mesh=None):
+    validate_mesh_flag(mesh)
+    window = make_window(num_requests, n_servers, seed=seed,
+                         gen_tokens=gen_tokens, n_cells=n_cells,
+                         drain_rate=drain_rate, arrival_rate=arrival_rate,
+                         scenario=scenario)
+    policy = resolve_policy_flag(policy, window.params,
+                                 sharded=mesh is not None)
+    reqs = window.reqs
+
+    # local reduced models actually generate tokens for routed requests
+    models = {}
+    if execute:
+        for e in build_catalog(EDGE_ARCHS):
+            cfg = reduced(get_arch(e.name))
+            models[e.index] = (cfg, lm.init_params(jax.random.key(e.index), cfg))
+
+    t0 = time.time()
+    fleet_state, out = route_window(window, policy, chunk=chunk,
+                                    backend=backend, mesh=mesh)
     jax.block_until_ready(out.choice)
     route_s = time.time() - t0
 
@@ -278,21 +317,19 @@ def serve(num_requests=32, n_servers=3, policy="greedy", execute=True, seed=0,
                     params, cache, tok, jnp.int32(P + t), cfg
                 )
 
-    # the cloud column is appended last when the fleet is multicell
-    stats = batch_router.stats(
-        out, cloud_index=len(fleet) - 1 if multicell else None
-    )
+    stats = batch_router.stats(out, cloud_index=window.cloud_index)
     stats["route_s"] = route_s
     stats["wall_s"] = time.time() - t0
     stats["requests"] = num_requests
     stats["cells"] = n_cells
-    stats["servers"] = len(fleet)
-    stats["scenario"] = spec.name
+    stats["servers"] = int(window.params.flops_per_s.shape[0])
+    stats["scenario"] = window.spec.name
     stats["seed"] = seed
     return stats
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--servers", type=int, default=3,

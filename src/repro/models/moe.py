@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.distributed.sharding import shard_map
 
 
 def moe_init(key, cfg: ArchConfig):
@@ -260,7 +259,7 @@ def moe_apply(params, x, cfg: ArchConfig, mesh=None):
             aux = jax.lax.pmean(aux, batch_axes)
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=specs_in,

@@ -13,8 +13,8 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
+from x64 import enable_x64
 from repro.core import batch_router as br
 from repro.core.catalog import build_catalog
 from repro.core.router import EdgeServer, ModelAwareRouter, Request

@@ -55,7 +55,7 @@ def test_compressed_psum_single_group_is_identity():
     x = jax.random.normal(jax.random.key(3), (300,))
 
     def f(v):
-        return sharding.shard_map(
+        return jax.shard_map(
             lambda a: compression.compressed_psum(a, "pod"), mesh=mesh,
             in_specs=jax.sharding.PartitionSpec(),
             out_specs=jax.sharding.PartitionSpec(),

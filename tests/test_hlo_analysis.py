@@ -55,7 +55,7 @@ def test_collectives_counted_with_ring_formula():
     mesh = sharding.make_mesh((1,), ("x",))
 
     def f(a):
-        return sharding.shard_map(
+        return jax.shard_map(
             lambda v: jax.lax.psum(v, "x"), mesh=mesh,
             in_specs=jax.sharding.PartitionSpec("x"),
             out_specs=jax.sharding.PartitionSpec(),

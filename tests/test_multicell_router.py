@@ -10,12 +10,13 @@ queue trace, and ``drain_rate == 0`` must reproduce the synchronous
 (PR 1) behaviour bit for bit.
 """
 import copy
+import json
 
 import numpy as np
 import pytest
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
+from x64 import enable_x64
 from repro.core import batch_router as br
 from repro.core.catalog import build_catalog
 from repro.core.router import CLOUD_CELL, EdgeServer, ModelAwareRouter, Request
@@ -814,3 +815,18 @@ def test_sharded_chunked_and_speculative_paths_agree():
                                    np.asarray(st.queue_tokens), rtol=1e-5)
     _sharded_outcome_equal(out_b, out_c)
     _sharded_state_equal(st_b, st_c)
+
+
+@pytest.mark.multidevice
+def test_chip_smoke_four_chip_path_on_host_devices(monkeypatch, capsys):
+    """``chip_smoke.py --chips 4`` rehearsed tiny on host devices: the
+    sharded metro window is device-count invariant, the cloud-free
+    variant equals plain ``route_batch``, and the D=4 outputs live on
+    four devices."""
+    from test_chip_smoke import rehearse
+
+    rehearse(monkeypatch).main(["--chips", "4"])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(out[-1])["ok"] is True
+    assert "  device-count invariance, D=4 vs D=1: bitwise equal" in out
+    assert "  cloud-free, D=4 vs plain route_batch: bitwise equal" in out

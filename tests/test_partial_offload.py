@@ -25,8 +25,8 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
+from x64 import enable_x64
 from repro.core import costs, env
 from repro.core.catalog import CatalogEntry
 from repro.core.router import EdgeServer, ModelAwareRouter, Request

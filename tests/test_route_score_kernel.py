@@ -260,3 +260,15 @@ def test_env_knob_resolves_backend(monkeypatch):
     monkeypatch.delenv(br.BACKEND_ENV)
     assert br.resolve_backend(None) == "xla"
     assert br.resolve_backend("pallas") == "pallas"
+
+
+def test_pallas_backend_never_emulates_on_cpu():
+    """``backend="pallas"`` always compiles the kernel: on a CPU backend
+    it raises instead of falling back to the interpreter."""
+    args = _random_case(np.random.default_rng(23), 9, 5, 4, jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        ops.route_score(**args, backend="pallas")
+    got = ops.route_score(**args, backend="pallas-interpret")
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ops.route_score(**args, backend="xla")),
+        rtol=1e-6)
