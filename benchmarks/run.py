@@ -120,15 +120,6 @@ def bench_score_kernel():
     score_kernel.main(shapes=((4096, 64),), header=False)
 
 
-def bench_score_roofline():
-    """Roofline terms (+ TPU timing when on TPU) for the route-score
-    kernel at B >= 64k, where the (B, N) panel exceeds VMEM; refreshes
-    benchmarks/BENCH_score_roofline.json."""
-    from benchmarks import score_roofline
-
-    score_roofline.main(header=False)
-
-
 def bench_multicell():
     """Multi-cell fleets + time-based drain, one jitted call per batch."""
     from benchmarks import multicell_throughput
@@ -253,7 +244,6 @@ SECTIONS = [
     ("maddpg_update", bench_maddpg_update),
     ("kernels", bench_kernels),
     ("score_kernel", bench_score_kernel),
-    ("score_roofline", bench_score_roofline),
     ("router_throughput", bench_router_throughput),
     ("multicell", bench_multicell),
     ("fleet_scale", bench_fleet_scale),

@@ -160,6 +160,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import costs
 from repro.core.router import (
     CAUSE_ADMISSION, CAUSE_COMPLETED, CAUSE_INFEASIBLE, CAUSE_OUTAGE,
@@ -819,10 +820,11 @@ def route_batch(
         ``False`` forces the plain correction scan (the A/B baseline).
     """
     backend = resolve_backend(backend)  # env read stays outside the jit cache
-    return _route_batch(params, state, reqs, drain_tokens, outage,
-                        policy=policy, actor=actor, chunk=chunk,
-                        unroll=unroll, backend=backend,
-                        speculative=speculative)
+    with obs.span("repro.route", requests=int(reqs.model.shape[0])):
+        return _route_batch(params, state, reqs, drain_tokens, outage,
+                            policy=policy, actor=actor, chunk=chunk,
+                            unroll=unroll, backend=backend,
+                            speculative=speculative)
 
 
 @functools.partial(
@@ -876,10 +878,11 @@ def _route_core(params, state, reqs, drain_tokens, policy_fn, *, chunk,
              jnp.asarray(time0, dtype))
 
     if chunk is None:
-        carry, outs = _scan_full(params, reqs, carry, policy_fn, dtype,
-                                 gen_tokens, drain, drain_rate, arrivals,
-                                 deadline, outage, has_cells, has_time,
-                                 unroll, eta, beta, local)
+        with jax.named_scope("route.commit_scan"):
+            carry, outs = _scan_full(params, reqs, carry, policy_fn, dtype,
+                                     gen_tokens, drain, drain_rate, arrivals,
+                                     deadline, outage, has_cells, has_time,
+                                     unroll, eta, beta, local)
     else:
         carry, outs = _scan_chunked(params, reqs, carry, policy_fn, dtype,
                                     gen_tokens, drain, drain_rate, arrivals,
@@ -1315,16 +1318,17 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         # base because re-subtracting it on residency would cancel
         # catastrophically (the download price dwarfs the served
         # latencies) — the scan re-gates it.
-        base = ops.route_score(
-            prompt_c, None, scal_c[:, 2], work_c,
-            params.uplink_bps, params.backhaul_bps, params.flops_per_s,
-            req_cell=cell_c,
-            srv_cell=params.cell if has_cells else None,
-            spill=params.spill if has_cells else None,
-            cloud_cell=CLOUD_CELL, backend=backend,
-        )                                                       # (c, N)
-        if outage is not None:
-            base = jnp.where(outage[None, :], jnp.inf, base)
+        with jax.named_scope("route.score"):
+            base = ops.route_score(
+                prompt_c, None, scal_c[:, 2], work_c,
+                params.uplink_bps, params.backhaul_bps, params.flops_per_s,
+                req_cell=cell_c,
+                srv_cell=params.cell if has_cells else None,
+                spill=params.spill if has_cells else None,
+                cloud_cell=CLOUD_CELL, backend=backend,
+            )                                                   # (c, N)
+            if outage is not None:
+                base = jnp.where(outage[None, :], jnp.inf, base)
 
         def inner_xs(aux):
             prompt_ctx = prompt_c if praw_c is None else praw_c
@@ -1334,8 +1338,9 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
                     graw_c if needs_ctx else None, tloc_c, aux)
 
         if not has_hook:
-            return jax.lax.scan(step, carry, inner_xs(None),
-                                unroll=min(unroll, c))
+            with jax.named_scope("route.commit_scan"):
+                return jax.lax.scan(step, carry, inner_xs(None),
+                                    unroll=min(unroll, c))
         # chunk-level policy hook: batch the expensive per-request work
         # (e.g. the actor MLP) over the whole chunk against the
         # CHUNK-ENTRY residency; the scan resolves each step against
@@ -1355,18 +1360,19 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
             cell=cell_c if has_cells else None,
         )
         aux = policy_fn.chunk_precompute(cctx)
-        fast_carry, fast_outs = jax.lax.scan(
-            step, carry, inner_xs(aux), unroll=min(unroll, c))
+        with jax.named_scope("route.commit_scan"):
+            fast_carry, fast_outs = jax.lax.scan(
+                step, carry, inner_xs(aux), unroll=min(unroll, c))
 
-        def keep(_):
-            return fast_carry, fast_outs[:, :3]
+            def keep(_):
+                return fast_carry, fast_outs[:, :3]
 
-        def replay(_):  # rerun the chunk through the per-request path
-            return jax.lax.scan(step, carry, inner_xs(None),
-                                unroll=min(unroll, c))
+            def replay(_):  # rerun the chunk through the per-request path
+                return jax.lax.scan(step, carry, inner_xs(None),
+                                    unroll=min(unroll, c))
 
-        return jax.lax.cond(jnp.all(fast_outs[:, 3] != 0.0),
-                            keep, replay, None)
+            return jax.lax.cond(jnp.all(fast_outs[:, 3] != 0.0),
+                                keep, replay, None)
 
     def spec_chunk_step(carry, xs):
         lru, queue, clock, time_s = carry
@@ -1376,24 +1382,25 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         idx_c = jnp.arange(c, dtype=jnp.int32)
 
         # phase 1 — the same switch-free base the correction scan uses...
-        base = ops.route_score(
-            prompt_c, None, ftok_c, work_c,
-            params.uplink_bps, params.backhaul_bps, params.flops_per_s,
-            req_cell=cell_c,
-            srv_cell=params.cell if has_cells else None,
-            spill=params.spill if has_cells else None,
-            cloud_cell=CLOUD_CELL, backend=backend,
-        )                                                    # (c, N)
-        if outage is not None:
-            base = jnp.where(outage[None, :], jnp.inf, base)
-        # ... plus the eq. 7 switch gate priced against the CHUNK-ENTRY
-        # residency, applied with the per-step expression verbatim: the
-        # speculative scores stay bitwise equal to the correction
-        # scan's on every step where residency has not yet drifted
-        hitrow = (lru[:num_k] < _LRU_FREE)[model_c]          # (c, N)
-        basez = base + jnp.where(
-            hitrow, 0.0, size_c[:, None] / params.backhaul_bps[None, :]
-        )
+        with jax.named_scope("route.score"):
+            base = ops.route_score(
+                prompt_c, None, ftok_c, work_c,
+                params.uplink_bps, params.backhaul_bps, params.flops_per_s,
+                req_cell=cell_c,
+                srv_cell=params.cell if has_cells else None,
+                spill=params.spill if has_cells else None,
+                cloud_cell=CLOUD_CELL, backend=backend,
+            )                                                    # (c, N)
+            if outage is not None:
+                base = jnp.where(outage[None, :], jnp.inf, base)
+            # ... plus the eq. 7 switch gate priced against the CHUNK-ENTRY
+            # residency, applied with the per-step expression verbatim: the
+            # speculative scores stay bitwise equal to the correction
+            # scan's on every step where residency has not yet drifted
+            hitrow = (lru[:num_k] < _LRU_FREE)[model_c]          # (c, N)
+            basez = base + jnp.where(
+                hitrow, 0.0, size_c[:, None] / params.backhaul_bps[None, :]
+            )
 
         def spec_step(carry, xs_b):
             queue, time_s = carry
@@ -1437,60 +1444,62 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
             return (queue, time_s), out
 
         inner = (basez, ftok_c, gen_c, drain_c, arr_c, valid_c, dl_c, tloc_c)
-        _, souts = jax.lax.scan(spec_step, (queue, time_s), inner,
-                                unroll=min(unroll, c))
-        choices = souts[0]
-        q_ext = jnp.concatenate([queue[None], souts[1]])     # (c+1, N)
-        # everything the cheap scan did NOT emit comes back exactly,
-        # vectorised, from the stored queue trajectory: re-running the
-        # body's own expressions on its own carried values is bitwise
-        q_pre = q_ext[:c]
-        if has_time:
-            t_ext = jnp.concatenate([time_s[None], souts[2]])
-            dt_v = jnp.maximum(arr_c - t_ext[:c], 0.0)
-            if valid_c is not None:
-                dt_v = jnp.where(valid_c, dt_v, 0.0)
-            q_pre = jnp.maximum(
-                q_pre - drain_rate[None, :] * dt_v[:, None], 0.0
-            )
-        lats_full = basez + (q_pre * ftok_c[:, None]) / \
-            params.flops_per_s[None, :]
-        col = choices[:, None]
-        lat = jnp.take_along_axis(lats_full, col, axis=1)[:, 0]
-        if tloc_c is not None:  # eq. 13: reported latency and SLO floor
-            lat = jnp.maximum(tloc_c, lat)
-        hits = jnp.take_along_axis(hitrow, col, axis=1)[:, 0]
-        ok = jnp.isfinite(lat) if has_mask else jnp.ones((c,), bool)
-        if dl_c is not None:  # re-derived `lat` is bitwise the scan's
-            ok &= lat <= dl_c
-        okv = ok if valid_c is None else ok & valid_c
-        # first conflicting commit: a committed MISS mutates residency
-        # (install + possible eviction), invalidating later frozen
-        # scores; committed HITS only touch LRU clocks, which no score
-        # reads — everything before the first miss is oracle-exact
-        miss = okv & ~hits
-        i0 = jnp.where(miss.any(), jnp.argmax(miss).astype(jnp.int32),
-                       jnp.int32(c))
-        # clock advances per VALID request, committed or not
-        cum = (idx_c + 1 if valid_c is None
-               else jnp.cumsum(valid_c.astype(jnp.int32)))
-        clocks = clock + cum                                 # (c,)
-        # parallel commit of the speculative prefix: ONE scatter-max
-        # applies every prefix hit's LRU clock (clocks grow with the
-        # stream index, so duplicate (model, server) slots resolve to
-        # the LATEST write — exactly the serial order); prefix queue
-        # adds already live in the trajectory
-        in_prefix = okv & hits & (idx_c < i0)
-        scat_col = jnp.where(in_prefix, choices, n)          # n: dump lane
-        lru = jnp.pad(lru, ((0, 0), (0, 1)))
-        lru = lru.at[model_c, scat_col].max(clocks)[:, :n]
-        # rewind carried state to the first conflicting commit ...
-        queue = jnp.take(q_ext, i0, axis=0)
-        clock = clock + jnp.where(i0 > 0, cum[jnp.maximum(i0 - 1, 0)], 0)
-        if has_time:
-            time_s = jnp.take(t_ext, i0, axis=0)
-        och = jnp.where(okv, choices, -1)
-        ohit = hits & okv
+        with jax.named_scope("route.spec_scan"):
+            _, souts = jax.lax.scan(spec_step, (queue, time_s), inner,
+                                    unroll=min(unroll, c))
+        with jax.named_scope("route.rederive"):
+            choices = souts[0]
+            q_ext = jnp.concatenate([queue[None], souts[1]])     # (c+1, N)
+            # everything the cheap scan did NOT emit comes back exactly,
+            # vectorised, from the stored queue trajectory: re-running the
+            # body's own expressions on its own carried values is bitwise
+            q_pre = q_ext[:c]
+            if has_time:
+                t_ext = jnp.concatenate([time_s[None], souts[2]])
+                dt_v = jnp.maximum(arr_c - t_ext[:c], 0.0)
+                if valid_c is not None:
+                    dt_v = jnp.where(valid_c, dt_v, 0.0)
+                q_pre = jnp.maximum(
+                    q_pre - drain_rate[None, :] * dt_v[:, None], 0.0
+                )
+            lats_full = basez + (q_pre * ftok_c[:, None]) / \
+                params.flops_per_s[None, :]
+            col = choices[:, None]
+            lat = jnp.take_along_axis(lats_full, col, axis=1)[:, 0]
+            if tloc_c is not None:  # eq. 13: reported latency and SLO floor
+                lat = jnp.maximum(tloc_c, lat)
+            hits = jnp.take_along_axis(hitrow, col, axis=1)[:, 0]
+            ok = jnp.isfinite(lat) if has_mask else jnp.ones((c,), bool)
+            if dl_c is not None:  # re-derived `lat` is bitwise the scan's
+                ok &= lat <= dl_c
+            okv = ok if valid_c is None else ok & valid_c
+            # first conflicting commit: a committed MISS mutates residency
+            # (install + possible eviction), invalidating later frozen
+            # scores; committed HITS only touch LRU clocks, which no score
+            # reads — everything before the first miss is oracle-exact
+            miss = okv & ~hits
+            i0 = jnp.where(miss.any(), jnp.argmax(miss).astype(jnp.int32),
+                           jnp.int32(c))
+            # clock advances per VALID request, committed or not
+            cum = (idx_c + 1 if valid_c is None
+                   else jnp.cumsum(valid_c.astype(jnp.int32)))
+            clocks = clock + cum                                 # (c,)
+            # parallel commit of the speculative prefix: ONE scatter-max
+            # applies every prefix hit's LRU clock (clocks grow with the
+            # stream index, so duplicate (model, server) slots resolve to
+            # the LATEST write — exactly the serial order); prefix queue
+            # adds already live in the trajectory
+            in_prefix = okv & hits & (idx_c < i0)
+            scat_col = jnp.where(in_prefix, choices, n)          # n: dump lane
+            lru = jnp.pad(lru, ((0, 0), (0, 1)))
+            lru = lru.at[model_c, scat_col].max(clocks)[:, :n]
+            # rewind carried state to the first conflicting commit ...
+            queue = jnp.take(q_ext, i0, axis=0)
+            clock = clock + jnp.where(i0 > 0, cum[jnp.maximum(i0 - 1, 0)], 0)
+            if has_time:
+                time_s = jnp.take(t_ext, i0, axis=0)
+            och = jnp.where(okv, choices, -1)
+            ohit = hits & okv
 
         def replay_body(i, st):
             # ... and replay the conflicting suffix serially with the
@@ -1544,9 +1553,10 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
             return (lru, queue, clk, ts, och, olat, ohit)
 
         st = (lru, queue, clock, time_s, och, lat, ohit)
-        lru, queue, clock, time_s, och, olat, ohit = jax.lax.fori_loop(
-            i0, c, replay_body, st
-        )
+        with jax.named_scope("route.replay"):
+            lru, queue, clock, time_s, och, olat, ohit = jax.lax.fori_loop(
+                i0, c, replay_body, st
+            )
         return (lru, queue, clock, time_s), (och, olat, ohit)
 
     # (c, 3) strip of per-request scalars: one xs slice per step.

@@ -222,5 +222,6 @@ def route_score(
         out_specs=pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), out_dtype),
         interpret=interpret,
+        name="route_score",  # the kernel's name in HLO and device traces
     )(*inputs)
     return out[:b, :n]

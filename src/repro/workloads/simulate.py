@@ -18,6 +18,11 @@ latencies, final state — pinned by ``tests/test_workloads.py``).
 Fixed-size windows also keep the jit cache small: every window shares
 one compiled program (+1 for a ragged tail).
 
+Each call is one ``repro.simulate`` span (``repro.obs``) with a
+``window``, ``wait`` and ``sample`` span a window and one ``stats`` span
+at the end, so the host's share of an episode can be told from the
+device's.
+
 ``benchmarks/scenario_suite.py`` runs this over the full policies x
 scenarios matrix; ``examples/serve_edge.py`` prints one time series.
 """
@@ -30,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import batch_router as br
 from repro.core import costs
 
@@ -190,72 +196,81 @@ def simulate(params: br.FleetParams, state: br.FleetState,
     b = int(reqs.model.shape[0])
     w = max(1, int(window_requests))
     n_windows = max(1, math.ceil(b / w))
-    outs, q50, q90, qmax = [], [], [], []
-    arr_np = (np.asarray(reqs.arrival_s)
-              if reqs.arrival_s is not None else None)
-    for i in range(n_windows):
-        sl = slice(i * w, min((i + 1) * w, b))
-        win = jax.tree.map(lambda x: x[sl], reqs)
-        dw = drain_tokens
-        if dw is not None and np.ndim(dw) == 1:
-            dw = dw[sl]
-        params_w, outage = params, None
-        if faults is not None:
-            t = float(arr_np[sl.start])  # the window's first arrival
-            om = _fault_mask(faults.outages, n_srv, t)
-            if om.any():
-                outage = jnp.asarray(om)
-            dm = _fault_mask(faults.drain_outages, n_srv, t)
-            if dm.any():  # stalled drain: still routable, backlog grows
-                params_w = params._replace(drain_rate=jnp.where(
-                    jnp.asarray(dm), 0.0, params.drain_rate))
-        if sharded:
-            state, out = mesh_router.route_batch_sharded(
-                params_w, state, win, mesh=mesh, num_devices=num_devices,
-                policy=policy, actor=actor, chunk=chunk, unroll=unroll,
-                backend=backend, outage=outage)
-        else:
-            state, out = br.route_batch(params_w, state, win, dw,
-                                        policy=policy, actor=actor,
-                                        chunk=chunk, unroll=unroll,
-                                        backend=backend, outage=outage)
-        outs.append(out)
-        q = np.asarray(state.queue_tokens)
-        if cloud_index is not None:
-            q = np.delete(q, cloud_index)
-        q50.append(np.percentile(q, 50))
-        q90.append(np.percentile(q, 90))
-        qmax.append(q.max())
+    with obs.span("repro.simulate", requests=b, windows=n_windows):
+        outs, q50, q90, qmax = [], [], [], []
+        arr_np = (np.asarray(reqs.arrival_s)
+                  if reqs.arrival_s is not None else None)
+        for i in range(n_windows):
+            with obs.span("repro.simulate.window"):
+                sl = slice(i * w, min((i + 1) * w, b))
+                win = jax.tree.map(lambda x: x[sl], reqs)
+                dw = drain_tokens
+                if dw is not None and np.ndim(dw) == 1:
+                    dw = dw[sl]
+                params_w, outage = params, None
+                if faults is not None:
+                    t = float(arr_np[sl.start])  # the window's first arrival
+                    om = _fault_mask(faults.outages, n_srv, t)
+                    if om.any():
+                        outage = jnp.asarray(om)
+                    dm = _fault_mask(faults.drain_outages, n_srv, t)
+                    # stalled drain: still routable, backlog grows
+                    if dm.any():
+                        params_w = params._replace(drain_rate=jnp.where(
+                            jnp.asarray(dm), 0.0, params.drain_rate))
+                if sharded:
+                    state, out = mesh_router.route_batch_sharded(
+                        params_w, state, win, mesh=mesh,
+                        num_devices=num_devices, policy=policy, actor=actor,
+                        chunk=chunk, unroll=unroll, backend=backend,
+                        outage=outage)
+                else:
+                    state, out = br.route_batch(params_w, state, win, dw,
+                                                policy=policy, actor=actor,
+                                                chunk=chunk, unroll=unroll,
+                                                backend=backend, outage=outage)
+                outs.append(out)
+            # the queue percentiles need this window's state on the host
+            with obs.span("repro.simulate.wait"):
+                jax.block_until_ready(state.queue_tokens)
+            with obs.span("repro.simulate.sample"):
+                q = np.asarray(state.queue_tokens)
+                if cloud_index is not None:
+                    q = np.delete(q, cloud_index)
+                q50.append(np.percentile(q, 50))
+                q90.append(np.percentile(q, 90))
+                qmax.append(q.max())
 
-    outcome = br.RouteOutcome(
-        *(jnp.concatenate([getattr(o, f) for o in outs])
-          for f in br.RouteOutcome._fields)
-    )
-    window_id = np.arange(b) // w
-    stats = br.window_stats(
-        outcome, window_id, n_windows, cloud_index=cloud_index,
-        completed_means={
-            "mean_energy_j": request_energy_j(params, reqs, outcome)
-        },
-    )
-    if reqs.arrival_s is not None:
-        arr = np.asarray(reqs.arrival_s)
-    else:  # no wall clock: use request indices as the time axis
-        arr = np.arange(b, dtype=float)
-    t0 = np.minimum.reduceat(arr, np.arange(0, b, w))
-    t1 = np.maximum.reduceat(arr, np.arange(0, b, w))
-    series = SimResult(
-        window_start_s=t0, window_end_s=t1,
-        requests=stats["requests"],
-        mean_latency=stats["mean_latency"],
-        mean_energy_j=stats["mean_energy_j"],
-        completion_rate=stats["completion_rate"],
-        residency_hit_rate=stats["residency_hit_rate"],
-        cloud_fallback_rate=stats.get("cloud_fallback_rate"),
-        queue_p50=np.asarray(q50), queue_p90=np.asarray(q90),
-        queue_max=np.asarray(qmax),
-        infeasible_rate=stats.get("infeasible_rate"),
-        admission_rate=stats.get("admission_rate"),
-        outage_rate=stats.get("outage_rate"),
-    )
+        with obs.span("repro.simulate.stats"):
+            outcome = br.RouteOutcome(
+                *(jnp.concatenate([getattr(o, f) for o in outs])
+                  for f in br.RouteOutcome._fields)
+            )
+            window_id = np.arange(b) // w
+            stats = br.window_stats(
+                outcome, window_id, n_windows, cloud_index=cloud_index,
+                completed_means={
+                    "mean_energy_j": request_energy_j(params, reqs, outcome)
+                },
+            )
+            if reqs.arrival_s is not None:
+                arr = np.asarray(reqs.arrival_s)
+            else:  # no wall clock: use request indices as the time axis
+                arr = np.arange(b, dtype=float)
+            t0 = np.minimum.reduceat(arr, np.arange(0, b, w))
+            t1 = np.maximum.reduceat(arr, np.arange(0, b, w))
+            series = SimResult(
+                window_start_s=t0, window_end_s=t1,
+                requests=stats["requests"],
+                mean_latency=stats["mean_latency"],
+                mean_energy_j=stats["mean_energy_j"],
+                completion_rate=stats["completion_rate"],
+                residency_hit_rate=stats["residency_hit_rate"],
+                cloud_fallback_rate=stats.get("cloud_fallback_rate"),
+                queue_p50=np.asarray(q50), queue_p90=np.asarray(q90),
+                queue_max=np.asarray(qmax),
+                infeasible_rate=stats.get("infeasible_rate"),
+                admission_rate=stats.get("admission_rate"),
+                outage_rate=stats.get("outage_rate"),
+            )
     return state, outcome, series

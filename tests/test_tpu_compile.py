@@ -3,8 +3,10 @@
 The TPU compiler is installed with JAX, so the Pallas score kernel and
 the chunked commit are compiled here at the widths the chip runs
 (``chip_smoke.py``): the compile refuses what interpret mode accepts —
-unaligned slices, too much fast memory, programs that do not fit. Each
-test asserts the kernel reached the HLO as a ``tpu_custom_call``.
+unaligned slices, too much fast memory, programs that do not fit. The
+tests assert the kernel reached the HLO as a ``tpu_custom_call``, and
+that each commit path's ``route.*`` named scopes reached the ops'
+``op_name`` metadata, where the device-trace readers look for them.
 
 The topology is described inside a module fixture, never at import:
 only one process may hold libtpu, and every test worker imports this
@@ -12,6 +14,7 @@ file. The persistent compilation cache is off around these compiles —
 an entry written for a described chip cannot be read back without one.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +59,12 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _scopes(compiled) -> set:
+    """The ``route.*`` named scopes in the compiled module's op_names."""
+    return set(re.findall(r'op_name="[^"]*?(route\.[a-z_]+)',
+                          compiled.as_text()))
+
+
 @pytest.mark.parametrize("b,n,cells", [(65536, 64, 0), (4096, 1025, 64)],
                          ids=["b65536_n64", "b4096_n1025_cells_spill"])
 def test_route_score_compiles_for_v5e(one_chip, b, n, cells):
@@ -88,4 +97,26 @@ def test_chunked_route_batch_compiles_for_v5e(one_chip, window):
         policy="greedy", actor=None, chunk=256, unroll=8, backend="pallas",
         speculative=True,
     )
-    _assert_kernel(lowered.compile())
+    compiled = lowered.compile()
+    _assert_kernel(compiled)
+    # the trace readers find the phases by these names
+    assert _scopes(compiled) == {"route.score", "route.spec_scan",
+                                 "route.rederive", "route.replay"}
+    assert re.search(r'op_name="[^"]*route\.score/route_score/pallas_call"',
+                     compiled.as_text())
+
+
+@pytest.mark.parametrize("chunk,speculative,scopes", [
+    (256, False, {"route.score", "route.commit_scan"}),
+    (None, True, {"route.commit_scan"}),
+], ids=["chunked_plain", "full_scan"])
+def test_route_scopes_of_the_other_commit_paths(one_chip, chunk, speculative,
+                                                scopes):
+    w = make_window(num_requests=4096, n_servers=64)
+    compiled = br._route_batch.lower(
+        _sds(w.params, one_chip), _sds(w.state, one_chip),
+        _sds(w.reqs, one_chip), w.drain_tokens, None,
+        policy="greedy", actor=None, chunk=chunk, unroll=8,
+        backend="pallas", speculative=speculative,
+    ).compile()
+    assert _scopes(compiled) == scopes
