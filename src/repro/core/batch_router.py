@@ -54,7 +54,9 @@ Design
   the fleet state only through the queue vector. A commit can invalidate
   a later provisional decision only by CHANGING a score it read —
   queue growth is carried exactly by a slimmed scan whose whole body is
-  ``argmin(base + queue*qcoef)`` plus one masked add, and the only
+  ``argmin(base + queue*qcoef)`` plus one masked add (one Pallas kernel
+  call per chunk on the Pallas backends, ``kernels/route_spec_scan.py``;
+  a ``lax.scan`` on ``"xla"``), and the only
   residency-mutating commits are misses (installs/evictions). Every
   decision up to the first committed miss is therefore the oracle
   decision; their LRU bookkeeping (hits only touch last-use clocks,
@@ -810,9 +812,10 @@ def route_batch(
         are identical either way. Batches that don't divide evenly are
         padded with inert requests that never touch the fleet.
       * ``unroll`` — lax.scan unroll factor for the sequential region.
-      * ``backend`` — scoring backend for the chunked phase-1 / the
-        fused kernel (``"xla"`` | ``"pallas"`` | ``"pallas-interpret"``;
-        ``None`` reads ``$REPRO_ROUTER_BACKEND``).
+      * ``backend`` — backend of the chunked path's kernels: the
+        phase-1 score panel and the speculative commit scan (``"xla"``
+        | ``"pallas"`` | ``"pallas-interpret"``; ``None`` reads
+        ``$REPRO_ROUTER_BACKEND``).
       * ``speculative`` — on the chunked greedy path, commit each
         chunk's provisional decisions speculatively and replay only the
         suffix after the first residency-mutating commit (see module
@@ -1402,75 +1405,25 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
                 hitrow, 0.0, size_c[:, None] / params.backhaul_bps[None, :]
             )
 
-        def spec_step(carry, xs_b):
-            queue, time_s = carry
-            basez_b, ftok_b, gen_b, drain_b, arrival_b, valid_b, dl_b, \
-                tloc_b = xs_b
-            if has_time:
-                dt = jnp.maximum(arrival_b - time_s, 0.0)
-                if valid_b is not None:
-                    dt = jnp.where(valid_b, dt, 0.0)
-                    time_s = jnp.where(valid_b,
-                                       jnp.maximum(time_s, arrival_b), time_s)
-                else:
-                    time_s = jnp.maximum(time_s, arrival_b)
-                queue = jnp.maximum(queue - drain_rate * dt, 0.0)
-            # the whole speculative recurrence: residency (and with it
-            # the argmin's score ordering) is FROZEN at chunk entry, so
-            # only the queue backlog rides the carry — score, argmin,
-            # one masked add. The choice itself is NOT emitted: the
-            # queue trajectory alone reproduces it post-scan, bitwise
-            lats = basez_b + (queue * ftok_b) / params.flops_per_s
-            choice = jnp.argmin(lats).astype(jnp.int32)
-            touch_n = iota_n == choice
-            if has_mask:
-                touch_n &= jnp.isfinite(basez_b[choice])
-            if dl_b is not None:
-                # greedy: lats[choice] IS the best score — the SLO check
-                best = lats[choice]
-                if tloc_b is not None:  # eq. 13 device-share floor
-                    best = jnp.maximum(tloc_b, best)
-                touch_n &= best <= dl_b
-            if valid_b is not None:
-                touch_n &= valid_b
-            queue = queue + jnp.where(touch_n, gen_b, 0.0)
-            if drain_b is not None:
-                d = drain_b if valid_b is None else jnp.where(valid_b,
-                                                              drain_b, 0.0)
-                if outage is not None:
-                    d = jnp.where(outage, 0.0, d)
-                queue = jnp.maximum(queue - d, 0.0)
-            out = (choice, queue) + ((time_s,) if has_time else ())
-            return (queue, time_s), out
-
-        inner = (basez, ftok_c, gen_c, drain_c, arr_c, valid_c, dl_c, tloc_c)
+        # the whole speculative recurrence: residency is FROZEN at chunk
+        # entry, so only the queue backlog rides the carry — one kernel
+        # call per chunk on the Pallas backends, a lax.scan on "xla"
         with jax.named_scope("route.spec_scan"):
-            _, souts = jax.lax.scan(spec_step, (queue, time_s), inner,
-                                    unroll=min(unroll, c))
+            q_ext, choices, lat, t_ext = ops.route_spec_scan(
+                basez, ftok_c, gen_c, queue, time_s, params.flops_per_s,
+                drain_rate=drain_rate, arrival=arr_c, drain=drain_c,
+                outage=outage, valid=valid_c, deadline=dl_c, tloc=tloc_c,
+                has_mask=has_mask, unroll=unroll, backend=backend,
+            )                                           # q_ext: (c+1, N)
         with jax.named_scope("route.rederive"):
-            choices = souts[0]
-            q_ext = jnp.concatenate([queue[None], souts[1]])     # (c+1, N)
-            # everything the cheap scan did NOT emit comes back exactly,
-            # vectorised, from the stored queue trajectory: re-running the
-            # body's own expressions on its own carried values is bitwise
-            q_pre = q_ext[:c]
-            if has_time:
-                t_ext = jnp.concatenate([time_s[None], souts[2]])
-                dt_v = jnp.maximum(arr_c - t_ext[:c], 0.0)
-                if valid_c is not None:
-                    dt_v = jnp.where(valid_c, dt_v, 0.0)
-                q_pre = jnp.maximum(
-                    q_pre - drain_rate[None, :] * dt_v[:, None], 0.0
-                )
-            lats_full = basez + (q_pre * ftok_c[:, None]) / \
-                params.flops_per_s[None, :]
+            # `lat` is the score every commit gate compared, on every
+            # backend, so `ok` and the trajectory's adds never disagree
             col = choices[:, None]
-            lat = jnp.take_along_axis(lats_full, col, axis=1)[:, 0]
             if tloc_c is not None:  # eq. 13: reported latency and SLO floor
                 lat = jnp.maximum(tloc_c, lat)
             hits = jnp.take_along_axis(hitrow, col, axis=1)[:, 0]
             ok = jnp.isfinite(lat) if has_mask else jnp.ones((c,), bool)
-            if dl_c is not None:  # re-derived `lat` is bitwise the scan's
+            if dl_c is not None:
                 ok &= lat <= dl_c
             okv = ok if valid_c is None else ok & valid_c
             # first conflicting commit: a committed MISS mutates residency

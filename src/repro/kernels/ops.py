@@ -97,3 +97,30 @@ def route_score(
         req_cell=req_cell, srv_cell=srv_cell, spill=spill,
         eta=eta, beta=beta, cloud_cell=cloud_cell,
     )
+
+
+def route_spec_scan(basez, ftok, gen, queue, time_s, flops_per_s, *,
+                    drain_rate=None, arrival=None, drain=None, outage=None,
+                    valid=None, deadline=None, tloc=None, has_mask=False,
+                    unroll=1, backend: str = "xla"):
+    """One chunk of the chunked router's speculative greedy commit scan
+    (see ``route_spec_scan.py``); returns ``(queues, choices, lats,
+    times)``: the queue trajectory (c+1, N) with the entry row first, the
+    choices, the score each commit gate compared, and the time trajectory.
+
+    Backends: ``"xla"`` (the reference ``lax.scan``), ``"pallas"`` (one
+    compiled TPU kernel call for the whole chunk) and
+    ``"pallas-interpret"`` (that kernel under the Pallas interpreter).
+    Both give the same results bit for bit."""
+    kw = dict(drain_rate=drain_rate, arrival=arrival, drain=drain,
+              outage=outage, valid=valid, deadline=deadline, tloc=tloc,
+              has_mask=has_mask, unroll=unroll)
+    if backend in _PALLAS:
+        from repro.kernels import route_spec_scan as _k
+
+        return _k.route_spec_scan(
+            basez, ftok, gen, queue, time_s, flops_per_s, **kw,
+            interpret=backend == "pallas-interpret",
+        )
+    return ref.spec_scan_xla(basez, ftok, gen, queue, time_s, flops_per_s,
+                             **kw)

@@ -280,3 +280,67 @@ def route_score_xla(
             visible = visible | spilled
         score = jnp.where(visible, score, jnp.inf)
     return score
+
+
+# ================ Speculative commit scan (greedy, one chunk) =================
+def spec_scan_xla(basez, ftok, gen, queue, time_s, flops_per_s, *,
+                  drain_rate=None, arrival=None, drain=None, outage=None,
+                  valid=None, deadline=None, tloc=None, has_mask=False,
+                  unroll=1):
+    """XLA reference of ``route_spec_scan.route_spec_scan``: the chunked
+    router's speculative greedy recurrence as a ``lax.scan``, one step a
+    request. Same arguments and results: ``(queues, choices, lats,
+    times)``, the queue trajectory (c+1, N) with the entry row first.
+
+    Residency (and with it the argmin's score ordering) is frozen at
+    chunk entry, so only the queue backlog rides the carry: score,
+    argmin, one masked add. Each step emits ``lats[choice]``, the score
+    its own gates compared, so the caller never re-derives it."""
+    c, n = basez.shape
+    iota_n = jnp.arange(n, dtype=jnp.int32)
+    has_time = drain_rate is not None
+
+    def spec_step(carry, xs_b):
+        queue, time_s = carry
+        basez_b, ftok_b, gen_b, drain_b, arrival_b, valid_b, dl_b, \
+            tloc_b = xs_b
+        if has_time:
+            dt = jnp.maximum(arrival_b - time_s, 0.0)
+            if valid_b is not None:
+                dt = jnp.where(valid_b, dt, 0.0)
+                time_s = jnp.where(valid_b,
+                                   jnp.maximum(time_s, arrival_b), time_s)
+            else:
+                time_s = jnp.maximum(time_s, arrival_b)
+            queue = jnp.maximum(queue - drain_rate * dt, 0.0)
+        lats = basez_b + (queue * ftok_b) / flops_per_s
+        choice = jnp.argmin(lats).astype(jnp.int32)
+        lat = lats[choice]
+        touch_n = iota_n == choice
+        if has_mask:
+            touch_n &= jnp.isfinite(basez_b[choice])
+        if dl_b is not None:
+            # greedy: lats[choice] IS the best score — the SLO check
+            best = lat
+            if tloc_b is not None:  # eq. 13 device-share floor
+                best = jnp.maximum(tloc_b, best)
+            touch_n &= best <= dl_b
+        if valid_b is not None:
+            touch_n &= valid_b
+        queue = queue + jnp.where(touch_n, gen_b, 0.0)
+        if drain_b is not None:
+            d = drain_b if valid_b is None else jnp.where(valid_b,
+                                                          drain_b, 0.0)
+            if outage is not None:
+                d = jnp.where(outage, 0.0, d)
+            queue = jnp.maximum(queue - d, 0.0)
+        out = (choice, lat, queue) + ((time_s,) if has_time else ())
+        return (queue, time_s), out
+
+    inner = (basez, ftok, gen, drain, arrival, valid, deadline, tloc)
+    _, souts = jax.lax.scan(spec_step, (queue, time_s), inner,
+                            unroll=min(unroll, c))
+    choices, lats = souts[0], souts[1]
+    queues = jnp.concatenate([queue[None], souts[2]])            # (c+1, N)
+    times = jnp.concatenate([time_s[None], souts[3]]) if has_time else None
+    return queues, choices, lats, times

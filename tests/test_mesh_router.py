@@ -231,6 +231,22 @@ def test_sharded_single_device_bitwise_vs_plain(policy, chunk):
     _assert_bitwise(st_p, out_p, st_s, out_s)
 
 
+def test_sharded_spec_scan_kernel_matches_xla():
+    """The speculative scan kernel batches under the sharded router's
+    ``vmap`` over cell blocks inside ``shard_map``: on
+    ``pallas-interpret`` the sharded greedy path routes decision for
+    decision, and leaves the fleet state bit for bit, as on ``xla``."""
+    rng = np.random.default_rng(10)
+    params, state = br.fleet_from_servers(
+        _fleet(rng, 4, 3, cloud=True, drain=2e4), CATALOG)
+    reqs = _stream(rng, 150, 4)
+    (st_x, out_x), (st_k, out_k) = (
+        mr.route_batch_sharded(params, state, reqs, chunk=16, backend=b,
+                               speculative=True, num_devices=1)
+        for b in ("xla", "pallas-interpret"))
+    _assert_bitwise(st_x, out_x, st_k, out_k)
+
+
 def test_sharded_auto_permutes_shuffled_fleet():
     """A non-cell-major fleet routes through an internal permutation and
     comes back in CALLER order — bitwise equal to the plain scan on the
