@@ -5,11 +5,17 @@ windowed into chunked ``route_batch`` calls.
 mask, time-based drain); the simulator's job is the EPISODE: it slices
 the stream into fixed-size request windows, routes each window with the
 ``FleetState`` carried from the previous one (LRU residency, queues,
-``time_s`` — nothing resets between windows), and aggregates a
-per-window time series on top of the concatenated outcome
-(``core.batch_router.window_stats``) plus queue-depth percentiles
-sampled at every window boundary — the only instants the queues are
-observable from outside the jitted call.
+``time_s`` — nothing resets between windows), and builds a per-window
+time series (``core.batch_router.window_stats``, one row a window) plus
+queue-depth percentiles sampled at every window boundary — the only
+instants the queues are observable from outside the jitted call.
+
+The window loop is a one-deep pipeline: the call's columns are split
+into windows once, window i+1 is dispatched before the host waits on
+window i, and window i's percentiles, stats row and energy are taken
+while i+1 runs, so the device waits only on the last window's
+bookkeeping. ``np.bincount`` sums each window in stream order, so a row
+taken alone equals the whole-stream call's row bit for bit.
 
 Because the scan commits requests strictly in stream order, windowing
 is a pure re-chunking: for a drain-free stream the W-window episode
@@ -21,13 +27,16 @@ one compiled program (+1 for a ragged tail).
 Each call is one ``repro.simulate`` span (``repro.obs``) with a
 ``window``, ``wait`` and ``sample`` span a window and one ``stats`` span
 at the end, so the host's share of an episode can be told from the
-device's.
+device's. Window i's ``wait`` and ``sample`` follow window i+1's
+``window``; ``sample`` counts ``in_flight``, the later windows already
+dispatched (1, and 0 for a call's last window).
 
 ``benchmarks/scenario_suite.py`` runs this over the full policies x
 scenarios matrix; ``examples/serve_edge.py`` prints one time series.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -131,6 +140,20 @@ def _fault_mask(windows, n: int, t: float) -> np.ndarray:
     return mask
 
 
+# the request columns ``request_energy_j`` reads, fetched once a call
+_ENERGY_COLUMNS = ("model", "prompt_bits", "gen_tokens", "eta")
+
+
+@functools.partial(jax.jit, static_argnames="w")
+def _split_windows(cols, w: int):
+    """``cols``, a pytree of (B,) columns, as its windows of ``w``
+    requests (full windows, then the ragged tail) in one program per
+    ``(B, w)``: one dispatch a call in place of one a column a window."""
+    b = jax.tree.leaves(cols)[0].shape[0]
+    return [jax.tree.map(lambda x: x[i:i + w], cols)
+            for i in range(0, max(b, 1), w)]
+
+
 def simulate(params: br.FleetParams, state: br.FleetState,
              reqs: br.RequestBatch, *, policy="greedy", actor=None,
              window_requests: int = 256, drain_tokens=None,
@@ -196,20 +219,45 @@ def simulate(params: br.FleetParams, state: br.FleetState,
     b = int(reqs.model.shape[0])
     w = max(1, int(window_requests))
     n_windows = max(1, math.ceil(b / w))
+    per_request_drain = drain_tokens is not None and np.ndim(drain_tokens) == 1
     with obs.span("repro.simulate", requests=b, windows=n_windows):
-        outs, q50, q90, qmax = [], [], [], []
-        arr_np = (np.asarray(reqs.arrival_s)
-                  if reqs.arrival_s is not None else None)
-        for i in range(n_windows):
+        outs, rows, q50, q90, qmax = [], [], [], [], []
+        arr_np = np.asarray(reqs.arrival_s) if faults is not None else None
+
+        def read_back(j, queue, out):
+            """Window ``j``'s queue percentiles and its row of the series,
+            on the host, while the windows after it run."""
+            with obs.span("repro.simulate.wait"):
+                jax.block_until_ready((queue, out))
+            with obs.span("repro.simulate.sample",
+                          in_flight=len(outs) - j - 1):
+                q = np.asarray(queue)
+                if cloud_index is not None:
+                    q = np.delete(q, cloud_index)
+                q50.append(np.percentile(q, 50))
+                q90.append(np.percentile(q, 90))
+                qmax.append(q.max())
+                out = jax.device_get(out)
+                sl = slice(j * w, min((j + 1) * w, b))
+                win = br.RequestBatch(**{f: None if v is None else v[sl]
+                                         for f, v in host.items()})
+                rows.append(br.window_stats(
+                    out, np.zeros(sl.stop - sl.start, np.int64), 1,
+                    cloud_index=cloud_index,
+                    completed_means={"mean_energy_j": request_energy_j(
+                        host_params, win, out)},
+                ))
+
+        wins = _split_windows(
+            (reqs, jnp.asarray(drain_tokens) if per_request_drain else None),
+            w)
+        for i, (win, dw) in enumerate(wins):
             with obs.span("repro.simulate.window"):
-                sl = slice(i * w, min((i + 1) * w, b))
-                win = jax.tree.map(lambda x: x[sl], reqs)
-                dw = drain_tokens
-                if dw is not None and np.ndim(dw) == 1:
-                    dw = dw[sl]
+                if not per_request_drain:
+                    dw = drain_tokens
                 params_w, outage = params, None
                 if faults is not None:
-                    t = float(arr_np[sl.start])  # the window's first arrival
+                    t = float(arr_np[i * w])  # the window's first arrival
                     om = _fault_mask(faults.outages, n_srv, t)
                     if om.any():
                         outage = jnp.asarray(om)
@@ -230,35 +278,29 @@ def simulate(params: br.FleetParams, state: br.FleetState,
                                                 chunk=chunk, unroll=unroll,
                                                 backend=backend, outage=outage)
                 outs.append(out)
-            # the queue percentiles need this window's state on the host
-            with obs.span("repro.simulate.wait"):
-                jax.block_until_ready(state.queue_tokens)
-            with obs.span("repro.simulate.sample"):
-                q = np.asarray(state.queue_tokens)
-                if cloud_index is not None:
-                    q = np.delete(q, cloud_index)
-                q50.append(np.percentile(q, 50))
-                q90.append(np.percentile(q, 90))
-                qmax.append(q.max())
+            if i == 0:  # the host's copy of the call, behind window 0
+                host_params = jax.device_get(params)
+                host = jax.device_get(
+                    {f: getattr(reqs, f) for f in _ENERGY_COLUMNS})
+                if reqs.arrival_s is not None:
+                    arr = (arr_np if arr_np is not None
+                           else np.asarray(reqs.arrival_s))
+                else:  # no wall clock: request indices as the time axis
+                    arr = np.arange(b, dtype=float)
+                t0 = np.minimum.reduceat(arr, np.arange(0, b, w))
+                t1 = np.maximum.reduceat(arr, np.arange(0, b, w))
+            else:
+                read_back(i - 1, *pending)
+            pending = (state.queue_tokens, out)
+        read_back(n_windows - 1, *pending)
 
         with obs.span("repro.simulate.stats"):
             outcome = br.RouteOutcome(
                 *(jnp.concatenate([getattr(o, f) for o in outs])
                   for f in br.RouteOutcome._fields)
             )
-            window_id = np.arange(b) // w
-            stats = br.window_stats(
-                outcome, window_id, n_windows, cloud_index=cloud_index,
-                completed_means={
-                    "mean_energy_j": request_energy_j(params, reqs, outcome)
-                },
-            )
-            if reqs.arrival_s is not None:
-                arr = np.asarray(reqs.arrival_s)
-            else:  # no wall clock: use request indices as the time axis
-                arr = np.arange(b, dtype=float)
-            t0 = np.minimum.reduceat(arr, np.arange(0, b, w))
-            t1 = np.maximum.reduceat(arr, np.arange(0, b, w))
+            stats = {k: np.concatenate([r[k] for r in rows])
+                     for k in rows[0]}
             series = SimResult(
                 window_start_s=t0, window_end_s=t1,
                 requests=stats["requests"],

@@ -90,8 +90,14 @@ def test_simulate_spans_one_episode(tiny):
     top = rec.spans[tops[0]]
     assert top.counts == {"requests": 200, "windows": 4}
     kids = [s.name for s in _children(rec, tops[0])]
-    assert kids == ["repro.simulate.window", "repro.simulate.wait",
-                    "repro.simulate.sample"] * 4 + ["repro.simulate.stats"]
+    # one-deep pipeline: window i's wait and sample follow window i+1's
+    # dispatch, and only the last window's read-back has nothing behind it
+    read_back = ["repro.simulate.wait", "repro.simulate.sample"]
+    assert kids == (["repro.simulate.window"] * 2 + read_back
+                    + (["repro.simulate.window"] + read_back) * 2
+                    + read_back + ["repro.simulate.stats"])
+    samples = [s for s in rec.spans if s.name == "repro.simulate.sample"]
+    assert [s.counts["in_flight"] for s in samples] == [1, 1, 1, 0]
     routes = [s for s in rec.spans if s.name == "repro.route"]
     assert [s.counts["requests"] for s in routes] == [64, 64, 64, 8]
     for s in routes:

@@ -8,14 +8,17 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import batch_router as br
 from repro.core.catalog import build_catalog
 from repro.launch.serve import make_multicell_fleet, serve
-from repro.workloads import (ScenarioSpec, compile_scenario, generators,
-                             get_scenario, list_scenarios, simulate)
+from repro.workloads import (FaultSpec, ScenarioSpec, SimResult,
+                             compile_scenario, generators, get_scenario,
+                             list_scenarios, simulate)
+from repro.workloads.simulate import request_energy_j
 
 EDGE_ARCHS = ["smollm_135m", "starcoder2_3b", "mamba2_2p7b",
               "musicgen_medium"]
@@ -194,6 +197,120 @@ def test_windowed_simulation_bit_matches_single_call(policy):
     # series shape checks: 300 requests in 64-windows -> 5 windows
     assert series.requests.tolist() == [64, 64, 64, 64, 44]
     assert (series.window_start_s[1:] >= series.window_end_s[:-1]).all()
+
+
+def _serial_episode(params, state, reqs, *, w, policy="greedy",
+                    drain_tokens=None, cloud_index=None, faults=None):
+    """The episode without a pipeline: route a window, wait for it, take
+    its queue percentiles, then stats and energy over the whole stream."""
+    b = int(reqs.model.shape[0])
+    n_srv = int(np.asarray(params.flops_per_s).shape[0])
+    n = max(1, -(-b // w))
+    arr = np.asarray(reqs.arrival_s)
+    outs, q50, q90, qmax = [], [], [], []
+    for i in range(n):
+        sl = slice(i * w, min((i + 1) * w, b))
+        win = jax.tree.map(lambda x: x[sl], reqs)
+        dw = drain_tokens
+        if dw is not None and np.ndim(dw) == 1:
+            dw = dw[sl]
+        p, outage = params, None
+        if faults is not None:
+            t = float(arr[sl.start])
+            down = np.zeros(n_srv, bool)
+            stall = np.zeros(n_srv, bool)
+            for srv, start, end in faults.outages:
+                down[srv] |= start <= t < end
+            for srv, start, end in faults.drain_outages:
+                stall[srv] |= start <= t < end
+            if down.any():
+                outage = jnp.asarray(down)
+            if stall.any():
+                p = params._replace(drain_rate=jnp.where(
+                    jnp.asarray(stall), 0.0, params.drain_rate))
+        state, out = br.route_batch(p, state, win, dw, policy=policy,
+                                    outage=outage)
+        outs.append(out)
+        q = np.asarray(state.queue_tokens)
+        if cloud_index is not None:
+            q = np.delete(q, cloud_index)
+        q50.append(np.percentile(q, 50))
+        q90.append(np.percentile(q, 90))
+        qmax.append(q.max())
+    outcome = br.RouteOutcome(*(jnp.concatenate([getattr(o, f) for o in outs])
+                                for f in br.RouteOutcome._fields))
+    stats = br.window_stats(
+        outcome, np.arange(b) // w, n, cloud_index=cloud_index,
+        completed_means={"mean_energy_j": request_energy_j(params, reqs,
+                                                           outcome)})
+    starts = np.arange(0, b, w)
+    series = SimResult(
+        window_start_s=np.minimum.reduceat(arr, starts),
+        window_end_s=np.maximum.reduceat(arr, starts),
+        requests=stats["requests"], mean_latency=stats["mean_latency"],
+        mean_energy_j=stats["mean_energy_j"],
+        completion_rate=stats["completion_rate"],
+        residency_hit_rate=stats["residency_hit_rate"],
+        cloud_fallback_rate=stats.get("cloud_fallback_rate"),
+        queue_p50=np.asarray(q50), queue_p90=np.asarray(q90),
+        queue_max=np.asarray(qmax),
+        infeasible_rate=stats.get("infeasible_rate"),
+        admission_rate=stats.get("admission_rate"),
+        outage_rate=stats.get("outage_rate"),
+    )
+    return state, outcome, series
+
+
+def _assert_same_bits(a, b, what):
+    if a is None or b is None:
+        assert a is None and b is None, what
+        return
+    a, b = np.asarray(a), np.asarray(b)
+    assert (a.dtype, a.shape) == (b.dtype, b.shape), what
+    assert a.tobytes() == b.tobytes(), what
+
+
+@pytest.mark.parametrize("case", ["cloud-ragged-tail", "drain-tokens",
+                                  "faults", "load", "single-window"])
+def test_pipelined_simulation_bit_matches_serial_episode(case):
+    catalog = build_catalog(EDGE_ARCHS)
+    fleet = make_multicell_fleet(2, 3, catalog, drain_rate=50.0)
+    params, state0 = br.fleet_from_servers(fleet, catalog)
+    reqs = compile_scenario(get_scenario("bursty", num_requests=300), seed=4,
+                            num_models=len(catalog), num_cells=2)
+    arr = np.asarray(reqs.arrival_s)
+    kw = {"w": 64, "cloud_index": len(fleet) - 1}
+    if case == "drain-tokens":
+        kw["drain_tokens"] = np.random.default_rng(4).uniform(
+            0.0, 40.0, 300).astype(np.float32)
+    elif case == "faults":
+        kw["w"] = 32
+        kw["faults"] = FaultSpec(
+            outages=((0, float(arr[60]), float(arr[150])),),
+            drain_outages=((2, float(arr[100]), float(arr[250])),))
+    elif case == "load":
+        kw["policy"] = "load"
+    elif case == "single-window":
+        kw["w"] = 512
+    want = _serial_episode(params, state0, reqs, **kw)
+    w = kw.pop("w")
+    got = simulate(params, state0, reqs, window_requests=w, **kw)
+    for field in br.FleetState._fields:
+        _assert_same_bits(getattr(got[0], field), getattr(want[0], field),
+                          f"state.{field}")
+    for field in br.RouteOutcome._fields:
+        _assert_same_bits(getattr(got[1], field), getattr(want[1], field),
+                          f"outcome.{field}")
+    for field in SimResult._fields:
+        _assert_same_bits(getattr(got[2], field), getattr(want[2], field),
+                          f"series.{field}")
+    if case == "faults":  # windows opening inside the outage avoid server 0
+        starts = arr[::w]
+        down = np.flatnonzero((starts >= arr[60]) & (starts < arr[150]))
+        assert down.size
+        choice = np.asarray(got[1].choice)
+        for i in down:
+            assert (choice[i * w:(i + 1) * w] != 0).all()
 
 
 def test_window_stats_matches_stats():
