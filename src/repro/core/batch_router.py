@@ -62,12 +62,12 @@ Design
   decision; their LRU bookkeeping (hits only touch last-use clocks,
   which no score reads) is applied in ONE vectorised scatter, and the
   conflicting suffix from the first miss onward is replayed serially
-  with the full correction body. Steady-state serving (hit rate near 1)
-  commits whole chunks speculatively; cold caches degrade gracefully to
-  the serial correction scan. Decisions and fleet state remain
-  bit-identical to the scalar oracle; ``speculative=False`` forces the
-  plain correction scan (the A/B baseline ``benchmarks/
-  router_throughput.py`` records).
+  through the correction scan's own per-request step. Steady-state
+  serving (hit rate near 1) commits whole chunks speculatively; cold
+  caches degrade gracefully to the serial correction scan. Decisions
+  and fleet state remain bit-identical to the scalar oracle;
+  ``speculative=False`` forces the plain correction scan (the A/B
+  baseline ``benchmarks/router_throughput.py`` records).
 * **Pluggable policies**: ``greedy`` (argmin of the eq. 11 latency),
   ``drain`` (drain-aware greedy: the queue backlog is discounted by the
   server's ``drain_rate`` before eq. 9 pricing), ``actor`` (a trained
@@ -147,7 +147,10 @@ request is scored every queue decays by ``drain_rate * dt`` with ``dt``
 the time elapsed since the carry clock last advanced. Queue decay thus
 tracks wall clock rather than request count. ``drain_rate == 0`` (or
 ``arrival_s=None``) reproduces the synchronous behaviour exactly; the
-legacy per-request ``drain_tokens`` argument is still honoured.
+legacy per-request ``drain_tokens`` argument is still honoured. Both
+drains are written once, in ``core.queue``, and every commit path (the
+scans here, both speculative scan backends and ``core.mesh_router``'s
+window-close replays) calls them.
 
 ``launch/serve.py`` exposes all of this end to end (``--policy
 {greedy,load,drain,actor:<ckpt>}``); ``docs/serving.md`` is the guide.
@@ -164,6 +167,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import costs
+from repro.core.queue import advance_to_arrival, drain_after_commit
 from repro.core.router import (
     CAUSE_ADMISSION, CAUSE_COMPLETED, CAUSE_INFEASIBLE, CAUSE_OUTAGE,
     CLOUD_CELL,
@@ -481,23 +485,6 @@ def local_block_params(params: FleetParams, layout: CellLayout,
 # ---------------------------------------------------------------------------
 # vectorised scoring
 # ---------------------------------------------------------------------------
-def _static_costs(params: FleetParams, reqs: RequestBatch, eta=None):
-    """State-independent pieces of the eq. 11 score, one shot per batch:
-    eq. 5 transmission (B, N), eq. 7 switch price (B, N) before the
-    residency gate, and per-request decode FLOPs/token (B,). ``eta``
-    scales the transmitted prompt — ``(x * eta) / r`` is the IEEE
-    grouping of eq. 5's ``x eta / r``, so ``None`` is bitwise eta=1."""
-    prompt = reqs.prompt_bits if eta is None else reqs.prompt_bits * eta
-    t_trans = costs.trans_latency(
-        prompt[:, None], 1.0, params.uplink_bps[None, :]
-    )
-    switch_price = costs.switch_latency(
-        params.size_bits[reqs.model][:, None], params.backhaul_bps[None, :]
-    )
-    flops_tok = params.decode_flops_per_token[reqs.model]
-    return t_trans, switch_price, flops_tok
-
-
 def _spill_adjacency(params: FleetParams, reqs: RequestBatch):
     """(B, N) bool: server reachable through the neighbour-cell spill
     adjacency (``None`` when the fleet carries no ``spill``). May overlap
@@ -521,7 +508,8 @@ def cell_mask(params: FleetParams, reqs: RequestBatch):
     ``CLOUD_CELL`` (the fleet-wide cloud-fallback column) OR reachable
     through the ``FleetParams.spill`` neighbour-cell adjacency. ``None``
     — returned when either side carries no cell ids — means "everything
-    visible" and lets callers compile the mask away statically."""
+    visible" and lets callers compile the mask away statically. Any
+    batch with a ``cell`` column will do (the scans pass ``_Columns``)."""
     if params.cell is None or reqs.cell is None:
         return None
     visible = (params.cell[None, :] == reqs.cell[:, None]) | (
@@ -712,12 +700,145 @@ def _resolve_policy(policy, actor):
 # ---------------------------------------------------------------------------
 # batched routing with sequential-commit semantics
 # ---------------------------------------------------------------------------
-def _commit(params, resident, last_use, queue, clock, model, gen_b, choice,
-            lats, ok):
-    """LRU residency + queue commit for one routed request, mirroring the
-    scalar oracle. ``ok=None`` commits unconditionally (the single-cell
+class _Plan(NamedTuple):
+    """How one router call compiles: the policy (a name or a callable,
+    and the actor a name may wrap) and ``route_batch``'s performance
+    knobs. Hashable, so a jitted entry point takes it as one static
+    argument."""
+
+    policy: Any
+    actor: Any
+    chunk: Optional[int]
+    unroll: int
+    backend: str
+    speculative: bool
+
+
+class _Columns(NamedTuple):
+    """A call's request columns, prepared once (``_columns``) for either
+    commit scan: cast to the fleet's float dtype, padded to whole chunks
+    and scaled by the eq. 16 knobs. A ``None`` field is an absent knob
+    and compiles out. Every field has the request axis first, so a
+    chunk's columns are one reshape of each and a request's one index.
+    The field order is the chunk loop's operand order in the compiled
+    program."""
+
+    model: jnp.ndarray     # (B,) int32 catalogue index
+    #: (B, 3): the committed tokens (eta-scaled), the eq. 7 model size
+    #: (+inf where beta refuses the download) and the decode FLOPs a
+    #: token, in one strip: a step reads its scalars in one slice
+    scal: jnp.ndarray
+    prompt: jnp.ndarray    # (B,) prompt bits the edge receives (eta-scaled)
+    work: jnp.ndarray      # (B,) decode FLOPs on the edge (eta-scaled)
+    drain: Optional[jnp.ndarray]     # (B,) per-request drain tokens
+    cell: Optional[jnp.ndarray]      # (B,) int32; None: untopologied
+    arrival: Optional[jnp.ndarray]   # (B,); None: no time drain
+    valid: Optional[jnp.ndarray]     # (B,) bool; False on padding
+    deadline: Optional[jnp.ndarray]  # (B,) SLO admission deadline
+    prompt_raw: Optional[jnp.ndarray]  # (B,) unscaled, for policies
+    gen_raw: Optional[jnp.ndarray]     # (B,) unscaled, for policies
+    tloc: Optional[jnp.ndarray]      # (B,) eq. 3 time of the 1 - eta
+
+
+def _columns(params, reqs, drain_tokens, dtype, chunk):
+    """``reqs`` as ``_Columns``. With a ``chunk`` the batch is padded to
+    whole chunks first, so each column is padded before it is scaled;
+    padded requests are inert (``valid`` False: no commit, no clock or
+    time advance)."""
+    b = reqs.model.shape[0]
+    pad = 0 if chunk is None else -b % max(1, min(int(chunk), b))
+
+    def pad1(x):
+        if not pad:
+            return x
+        return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+
+    model = pad1(reqs.model)
+    prompt = pad1(reqs.prompt_bits.astype(dtype))
+    gen = pad1(reqs.gen_tokens.astype(dtype))
+    flops_tok = params.decode_flops_per_token[model]
+    size_bits = params.size_bits[model]
+    work = gen * flops_tok
+    # eq. 16 knobs: eta pre-scales the edge share (prompt, work and the
+    # committed tokens — same IEEE grouping as the oracle), beta=False
+    # poisons the eq. 7 size to +inf (refused downloads never win), and
+    # `tloc` prices the device's eq. 3 share, entering only the reported
+    # eq. 13 latency and the SLO check — never the argmin
+    praw = graw = tloc = None
+    if reqs.eta is not None:
+        eta = pad1(reqs.eta.astype(dtype))
+        if reqs.local_flops_per_s is not None:
+            # eq. 3 on the UNSCALED work; a rate <= 0 means no device
+            local = pad1(reqs.local_flops_per_s.astype(dtype))
+            tloc = jnp.where(local > 0, ((1.0 - eta) * work) / local, 0.0)
+        praw, graw = prompt, gen  # policies still see the raw columns
+        prompt, work, gen = prompt * eta, work * eta, gen * eta
+    if reqs.beta is not None:
+        # padding pads False -> +inf size on pad rows; `valid` rejects them
+        beta = pad1(jnp.asarray(reqs.beta).astype(bool))
+        size_bits = jnp.where(beta, size_bits, jnp.inf)
+    has_cells = params.cell is not None and reqs.cell is not None
+    has_time = params.drain_rate is not None and reqs.arrival_s is not None
+    return _Columns(
+        model=model, scal=jnp.stack([gen, size_bits, flops_tok], axis=1),
+        prompt=prompt, work=work,
+        drain=None if drain_tokens is None else pad1(jnp.broadcast_to(
+            jnp.asarray(drain_tokens, dtype), reqs.model.shape)),
+        cell=pad1(reqs.cell) if has_cells else None,
+        arrival=pad1(reqs.arrival_s.astype(dtype)) if has_time else None,
+        valid=(jnp.arange(b + pad) < b) if pad else None,
+        # padded deadline lanes are 0.0 — harmless, `valid` rejects them
+        deadline=(None if reqs.deadline_s is None
+                  else pad1(reqs.deadline_s.astype(dtype))),
+        prompt_raw=praw, gen_raw=graw, tloc=tloc,
+    )
+
+
+def _policy_view(cols):
+    """The ``PolicyCtx``/``ChunkPolicyCtx`` columns of one request or one
+    chunk of ``_Columns``: policies see the unscaled prompt and tokens."""
+    return dict(
+        model=cols.model,
+        prompt_bits=cols.prompt if cols.prompt_raw is None
+        else cols.prompt_raw,
+        gen_tokens=cols.scal[..., 0] if cols.gen_raw is None
+        else cols.gen_raw,
+        flops_tok=cols.scal[..., 2], cell=cols.cell,
+    )
+
+
+def _observe(resident_m, queue, flops_per_s):
+    """The scalar router's (3N,) observation: ``[resident, queue,
+    flops]`` per server."""
+    return jnp.stack([resident_m.astype(queue.dtype), queue, flops_per_s],
+                     axis=-1).reshape(-1)
+
+
+def _clamped(choice, lats, has_mask):
+    """A policy's ``choice``, or the masked greedy argmin where that is
+    not a server the request sees. An actor may ignore the inf-masked
+    inputs or return an index outside [0, N), which a JAX gather would
+    silently clamp to server N-1: an out-of-cell or out-of-range choice
+    is never committed."""
+    safe = jnp.clip(choice, 0, lats.shape[0] - 1)
+    choice_ok = choice == safe
+    if has_mask:
+        # lats' finiteness (not the transmission panel's): a
+        # beta-refused pick falls back to the resident-only argmin, like
+        # the oracle
+        choice_ok &= jnp.isfinite(lats[safe])
+    return jnp.where(choice_ok, safe, jnp.argmin(lats).astype(jnp.int32))
+
+
+def _commit(params, fleet, clock, model, gen_b, choice, ok):
+    """LRU residency + queue fold of one request committed at ``choice``,
+    mirroring the scalar oracle; ``fleet`` is ``(resident, last_use,
+    queue)``. ``ok=None`` commits unconditionally (the single-cell
     un-padded fast path); a boolean ``ok`` gates every mutation — False
-    leaves the fleet untouched and reports a rejection (choice -1)."""
+    leaves the fleet untouched. Returns ``(fleet, was_resident)``. The
+    single scan commits through it, and so does ``core.mesh_router``'s
+    window-close replay."""
+    resident, last_use, queue = fleet
     row = resident[choice]
     was_resident = row[model]
     full = row.sum() >= params.cache_slots[choice]
@@ -731,7 +852,6 @@ def _commit(params, resident, last_use, queue, clock, model, gen_b, choice,
         resident = resident.at[choice].set(row)
         last_use = last_use.at[choice, model].set(clock)
         queue = queue.at[choice].add(gen_b)
-        out = (choice, lats[choice], was_resident)
     else:
         evict = ~was_resident & full & ok
         row = row.at[evict_idx].set(row[evict_idx] & ~evict)
@@ -741,8 +861,7 @@ def _commit(params, resident, last_use, queue, clock, model, gen_b, choice,
             jnp.where(ok, clock, last_use[choice, model])
         )
         queue = queue.at[choice].add(jnp.where(ok, gen_b, 0.0))
-        out = (jnp.where(ok, choice, -1), lats[choice], was_resident & ok)
-    return resident, last_use, queue, out
+    return (resident, last_use, queue), was_resident
 
 
 def route_batch(
@@ -836,64 +955,44 @@ def route_batch(
 )
 def _route_batch(params, state, reqs, drain_tokens, outage, *, policy, actor,
                  chunk, unroll, backend, speculative=True):
-    policy_fn = _resolve_policy(policy, actor)
-    return _route_core(params, state, reqs, drain_tokens, policy_fn,
-                       chunk=chunk, unroll=unroll, backend=backend,
-                       speculative=speculative, outage=outage)
+    return _route_core(params, state, reqs, drain_tokens, outage,
+                       _Plan(policy, actor, chunk, unroll, backend,
+                             speculative))
 
 
-def _route_core(params, state, reqs, drain_tokens, policy_fn, *, chunk,
-                unroll, backend, speculative=True, outage=None):
-    """The traceable body of :func:`route_batch` with the policy already
-    resolved to a callable — ``core.mesh_router`` vmaps exactly this over
-    cell blocks, so it must stay jit-free and policy-static."""
+def _route_core(params, state, reqs, drain_tokens, outage, plan):
+    """The traceable body of :func:`route_batch` under a ``_Plan`` —
+    ``core.mesh_router`` vmaps exactly this over cell blocks, so it must
+    stay jit-free."""
+    policy_fn = _resolve_policy(plan.policy, plan.actor)
     dtype = jnp.result_type(reqs.prompt_bits, params.uplink_bps)
-
-    gen_tokens = reqs.gen_tokens.astype(dtype)                  # (B,)
-    drain = (
-        None
-        if drain_tokens is None
-        else jnp.broadcast_to(jnp.asarray(drain_tokens, dtype),
-                              reqs.model.shape)
-    )
-    has_cells = params.cell is not None and reqs.cell is not None
-    has_time = params.drain_rate is not None and reqs.arrival_s is not None
+    cols = _columns(params, reqs, drain_tokens, dtype, plan.chunk)
     if outage is not None:
         outage = jnp.asarray(outage, bool)
-    drain_rate = params.drain_rate.astype(dtype) if has_time else None
-    if drain_rate is not None and outage is not None:
-        # frozen queue: an outaged server stops draining for this call
-        drain_rate = jnp.where(outage, 0.0, drain_rate)
-    arrivals = reqs.arrival_s.astype(dtype) if has_time else None
-    deadline = (reqs.deadline_s.astype(dtype)
-                if reqs.deadline_s is not None else None)
-    # eq. 16 knobs (compiled out when absent): eta scales the offloaded
-    # share, beta gates the eq. 7 download, local prices the eq. 3 side
-    eta = reqs.eta.astype(dtype) if reqs.eta is not None else None
-    beta = (jnp.asarray(reqs.beta).astype(bool)
-            if reqs.beta is not None else None)
-    local = (reqs.local_flops_per_s.astype(dtype)
-             if eta is not None and reqs.local_flops_per_s is not None
-             else None)
+    rate = None  # the time drain's rate, per server
+    if cols.arrival is not None:
+        rate = params.drain_rate.astype(dtype)
+        if outage is not None:
+            # frozen queue: an outaged server stops draining for this call
+            rate = jnp.where(outage, 0.0, rate)
+    # some score may be +inf: out of cell, outaged or a refused download
+    has_mask = (cols.cell is not None or outage is not None
+                or reqs.beta is not None)
     time0 = state.time_s if state.time_s is not None else 0.0
     carry = (state.resident, state.last_use,
              state.queue_tokens.astype(dtype), state.clock,
              jnp.asarray(time0, dtype))
 
-    if chunk is None:
+    if plan.chunk is None:
         with jax.named_scope("route.commit_scan"):
-            carry, outs = _scan_full(params, reqs, carry, policy_fn, dtype,
-                                     gen_tokens, drain, drain_rate, arrivals,
-                                     deadline, outage, has_cells, has_time,
-                                     unroll, eta, beta, local)
+            carry, outs = _scan_full(params, cols, carry, policy_fn, rate,
+                                     outage, has_mask, plan.unroll)
     else:
-        carry, outs = _scan_chunked(params, reqs, carry, policy_fn, dtype,
-                                    gen_tokens, drain, drain_rate, arrivals,
-                                    deadline, outage, has_cells, has_time,
-                                    chunk, unroll, backend, speculative,
-                                    eta, beta, local)
+        carry, outs = _scan_chunked(params, cols, carry, policy_fn, rate,
+                                    outage, has_mask, plan)
     resident, last_use, queue, clock, time_s = carry
-    choice, latency, hit = outs
+    b = reqs.model.shape[0]
+    choice, latency, hit = (x[:b] for x in outs)  # drop the chunk padding
     new_state = FleetState(
         resident=resident, last_use=last_use, queue_tokens=queue, clock=clock,
         time_s=time_s,
@@ -904,9 +1003,8 @@ def _route_core(params, state, reqs, drain_tokens, policy_fn, *, chunk,
     )
 
 
-def _scan_full(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
-               drain_rate, arrivals, deadline, outage, has_cells, has_time,
-               unroll, eta=None, beta=None, local=None):
+def _scan_full(params, cols, carry, policy_fn, rate, outage, has_mask,
+               unroll):
     """Single-scan path: full eq. 11 re-derivation per step (bit-exact
     latencies vs the scalar oracle — same term order, same rounding).
 
@@ -916,41 +1014,32 @@ def _scan_full(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
     stays a pure add chain. The surcharge lands ON the eq. 5 term
     before the eq. 7/9 adds, matching the oracle's term order bitwise.
 
-    Eq. 16 knobs: ``eta`` pre-scales the eq. 5/9 edge share (and the
-    commit queues ``eta * gen``); ``beta=False`` rows poison the eq. 7
-    switch price to ``+inf`` (a refused download can never win — and a
-    committed refusal is always a residency hit by construction);
-    ``local`` prices the device's retained ``1 - eta`` share (eq. 3),
-    which enters only the reported eq. 13 latency and the SLO check —
-    never the argmin (``max`` with a constant is monotone in the edge
-    score, so the edge argmin is already an eq. 13 argmin)."""
-    t_trans, switch_price, flops_tok = _static_costs(params, reqs, eta)
-    prompt_eff = (reqs.prompt_bits if eta is None
-                  else reqs.prompt_bits * eta)
-    if has_cells and params.spill is not None:
-        adj = _spill_adjacency(params, reqs)
-        spilled = adj & (params.cell[None, :] != reqs.cell[:, None])
+    Eq. 16 knobs arrive folded into ``cols``: the eta-scaled eq. 5/9
+    edge share (and committed tokens), the beta-poisoned eq. 7 size (a
+    refused download can never win — and a committed refusal is always
+    a residency hit by construction), and ``tloc``, the device's
+    retained ``1 - eta`` share (eq. 3), which enters only the reported
+    eq. 13 latency and the SLO check — never the argmin (``max`` with a
+    constant is monotone in the edge score, so the edge argmin is
+    already an eq. 13 argmin)."""
+    t_trans = costs.trans_latency(
+        cols.prompt[:, None], 1.0, params.uplink_bps[None, :]
+    )
+    switch_price = costs.switch_latency(
+        cols.scal[:, 1][:, None], params.backhaul_bps[None, :]
+    )
+    adj = _spill_adjacency(params, cols)
+    if adj is not None:
+        spilled = adj & (params.cell[None, :] != cols.cell[:, None])
         t_trans = t_trans + jnp.where(
-            spilled,
-            prompt_eff[:, None] / params.backhaul_bps[None, :], 0.0,
+            spilled, cols.prompt[:, None] / params.backhaul_bps[None, :], 0.0,
         )
-    vis = cell_mask(params, reqs)
+    vis = cell_mask(params, cols)
     if vis is not None:
         t_trans = jnp.where(vis, t_trans, jnp.inf)
     if outage is not None:
         t_trans = jnp.where(outage[None, :], jnp.inf, t_trans)
-    if beta is not None:
-        switch_price = jnp.where(beta[:, None], switch_price, jnp.inf)
-    has_mask = vis is not None or outage is not None or beta is not None
-    work = gen_tokens * flops_tok                               # (B,)
-    tloc = None
-    if eta is not None:
-        if local is not None:  # eq. 3 on the UNSCALED work; <= 0: no device
-            tloc = jnp.where(local > 0, ((1.0 - eta) * work) / local, 0.0)
-        work = work * eta
-    gen_eff = None if eta is None else gen_tokens * eta
     needs_ctx = getattr(policy_fn, "needs_ctx", False)
-    prompt = reqs.prompt_bits if needs_ctx else None
     # the builtin argmins return indices in [0, N) by construction and
     # can only land out of cell when the whole row is +inf (-> rejected
     # either way): skip the fallback clamp for them
@@ -958,84 +1047,60 @@ def _scan_full(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
 
     def step(carry, xs):
         resident, last_use, queue, clock, time_s = carry
-        (model, t_trans_b, switch_b, flops_tok_b, work_b, drain_b, gen_b,
-         cell_b, arrival_b, prompt_b, dl_b, gen_eff_b, tloc_b) = xs
+        row, t_trans_b, switch_b = xs
+        gen_b, ftok_b = row.scal[0], row.scal[2]
 
-        if has_time:  # wall-clock queue decay since the last arrival
-            dt = jnp.maximum(arrival_b - time_s, 0.0)
-            queue = jnp.maximum(queue - drain_rate * dt, 0.0)
-            time_s = jnp.maximum(time_s, arrival_b)
+        if rate is not None:  # wall-clock queue decay since the last arrival
+            queue, time_s = advance_to_arrival(queue, time_s, row.arrival,
+                                               rate)
         clock = clock + 1
 
-        resident_m = resident[:, model]                         # (N,)
+        resident_m = resident[:, row.model]                     # (N,)
         t_switch = jnp.where(resident_m, 0.0, switch_b)
-        t_comp = (queue * flops_tok_b + work_b) / params.flops_per_s
+        t_comp = (queue * ftok_b + row.work) / params.flops_per_s
         lats = t_trans_b + t_switch + t_comp                    # eq. 11
         queue_vis = queue
         if has_mask:  # masked servers can never win the argmin
             queue_vis = jnp.where(jnp.isfinite(t_trans_b), queue, jnp.inf)
 
-        if getattr(policy_fn, "needs_obs", True):
-            # scalar _observe layout: [resident, queue, flops] per server
-            obs = jnp.stack(
-                [resident_m.astype(dtype), queue, params.flops_per_s], axis=-1
-            ).reshape(-1)                                       # (3N,)
-        else:
-            obs = None
+        obs = (_observe(resident_m, queue, params.flops_per_s)
+               if getattr(policy_fn, "needs_obs", True) else None)
         if needs_ctx:
-            ctx = PolicyCtx(
-                params=params, model=model, prompt_bits=prompt_b,
-                gen_tokens=gen_b, flops_tok=flops_tok_b,
-                resident=resident_m, queue=queue,
-                cell=cell_b if has_cells else None,
-            )
+            ctx = PolicyCtx(params=params, resident=resident_m, queue=queue,
+                            **_policy_view(row))
             choice = jnp.asarray(policy_fn(lats, obs, queue_vis, ctx),
                                  jnp.int32)
         else:
             choice = jnp.asarray(policy_fn(lats, obs, queue_vis), jnp.int32)
         if needs_clamp:
-            # an actor may ignore the inf-masked inputs or return an
-            # index outside [0, N) — which a JAX gather would silently
-            # clamp to server N-1. Never commit an out-of-cell or
-            # out-of-range choice: fall back to the masked greedy argmin.
-            safe = jnp.clip(choice, 0, lats.shape[0] - 1)
-            choice_ok = choice == safe
-            if has_mask:
-                # lats (not t_trans) finiteness: a beta-refused pick
-                # falls back to the resident-only argmin, like the
-                # oracle; pre-beta the two conditions are identical
-                choice_ok &= jnp.isfinite(lats[safe])
-            choice = jnp.where(choice_ok, safe,
-                               jnp.argmin(lats).astype(jnp.int32))
+            choice = _clamped(choice, lats, has_mask)
 
         # a cell with no members and no cloud column (or fully outaged)
         # leaves every candidate at inf: reject without committing; the
         # SLO check compares the BEST score — policy-independent, so an
         # admission rejection never depends on which server was picked
         ok = jnp.isfinite(lats[choice]) if has_mask else None
-        if dl_b is not None:
+        if row.deadline is not None:
             best = jnp.min(lats)
-            if tloc_b is not None:  # eq. 13: the device share bounds below
-                best = jnp.maximum(tloc_b, best)
-            admit = best <= dl_b
+            if row.tloc is not None:  # eq. 13: the device share bounds below
+                best = jnp.maximum(row.tloc, best)
+            admit = best <= row.deadline
             ok = admit if ok is None else ok & admit
-        resident, last_use, queue, out = _commit(
-            params, resident, last_use, queue, clock, model,
-            gen_b if gen_eff_b is None else gen_eff_b, choice,
-            lats, ok,
+        (resident, last_use, queue), hit = _commit(
+            params, (resident, last_use, queue), clock, row.model, gen_b,
+            choice, ok,
         )
-        if tloc_b is not None:  # reported latency is eq. 13's max
-            out = (out[0], jnp.maximum(tloc_b, out[1]), out[2])
-        if drain_b is not None:  # None is static: compiled out of the scan
-            d = (drain_b if outage is None
-                 else jnp.where(outage, 0.0, drain_b))
-            queue = jnp.maximum(queue - d, 0.0)
-        return (resident, last_use, queue, clock, time_s), out
+        lat_b = lats[choice]
+        if row.tloc is not None:  # reported latency is eq. 13's max
+            lat_b = jnp.maximum(row.tloc, lat_b)
+        if ok is not None:
+            choice, hit = jnp.where(ok, choice, -1), hit & ok
+        if row.drain is not None:  # None is static: compiled out of the scan
+            queue = drain_after_commit(queue, row.drain, frozen=outage)
+        return (resident, last_use, queue, clock, time_s), (choice, lat_b, hit)
 
-    xs = (reqs.model, t_trans, switch_price, flops_tok, work, drain,
-          gen_tokens, reqs.cell if has_cells else None, arrivals, prompt,
-          deadline, gen_eff, tloc)
-    return jax.lax.scan(step, carry, xs, unroll=unroll)
+    return jax.lax.scan(step, carry, (cols, t_trans, switch_price),
+                        unroll=unroll)
 
 
 _LRU_FREE = jnp.iinfo(jnp.int32).max  # lru_key for a non-resident slot
@@ -1062,13 +1127,12 @@ def _static_argmin(col, k):
     return idxs[0]
 
 
-def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
-                  drain_rate, arrivals, deadline, outage, has_cells, has_time,
-                  chunk, unroll, backend, speculative=True,
-                  eta=None, beta=None, local=None):
+def _scan_chunked(params, cols, carry, policy_fn, rate, outage, has_mask,
+                  plan):
     """Two-phase commit: fused chunk scoring + slimmed correction scan,
     with the speculative parallel commit on top for the greedy policy
-    (``speculative=True``; see the module docstring for the argument).
+    (``plan.speculative``; see the module docstring for the argument).
+    ``cols`` holds whole chunks (``_columns`` padded it).
 
     The serial region also runs on a denser state encoding than the
     public ``FleetState`` (converted at entry/exit):
@@ -1093,54 +1157,11 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
     back as their pre-batch values (the single-scan path keeps the
     eviction-time clock); those entries are dead state — the oracle
     never reads a non-resident clock."""
-    b = reqs.model.shape[0]
+    b = cols.model.shape[0]
     n = params.flops_per_s.shape[0]
-    c = max(1, min(int(chunk), b))
-    n_chunks = -(-b // c)
-    pad = n_chunks * c - b
-
-    def pad1(x):
-        return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
-
-    model = pad1(reqs.model)
-    prompt = pad1(reqs.prompt_bits.astype(dtype))
-    gen = pad1(gen_tokens)
-    flops_tok = params.decode_flops_per_token[model]
-    size_bits = params.size_bits[model]
-    work = gen * flops_tok
-    # eq. 16 knobs: eta pre-scales the edge share (prompt, work, and the
-    # committed gen — same IEEE grouping as the oracle), beta=False
-    # poisons the eq. 7 size to +inf (refused downloads never win), and
-    # `local` prices the device's eq. 3 share, entering only the
-    # reported eq. 13 latency and the SLO check — never the argmin
-    tloc = None
-    if eta is not None:
-        eta_p = pad1(eta)
-        if local is not None:
-            local_p = pad1(local)
-            tloc = jnp.where(local_p > 0,
-                             ((1.0 - eta_p) * work) / local_p, 0.0)
-        prompt_eff = prompt * eta_p
-        work = work * eta_p
-        gen_commit = gen * eta_p
-        praw, graw = prompt, gen  # policies still see the raw columns
-    else:
-        prompt_eff, gen_commit = prompt, gen
-        praw = graw = None
-    if beta is not None:
-        # pad1 pads False -> +inf size on pad rows; `valid` rejects them
-        size_bits = jnp.where(pad1(beta), size_bits, jnp.inf)
-    cells = pad1(reqs.cell) if has_cells else None
-    arrs = pad1(arrivals) if has_time else None
-    drains = pad1(drain) if drain is not None else None
-    # padded deadline lanes are 0.0 — harmless, `valid` already rejects
-    dls = pad1(deadline) if deadline is not None else None
-    # padded tail requests are inert: no commit, no clock/time advance
-    valid = (jnp.arange(n_chunks * c) < b) if pad else None
-    # visibility rides in `base` as +inf; the outage mask folds into the
-    # same channel (and the beta-poisoned switch price reaches `lats`
-    # directly), so every downstream finiteness check covers all three
-    has_mask = has_cells or outage is not None or beta is not None
+    c = max(1, min(int(plan.chunk), b))
+    dtype = cols.prompt.dtype
+    has_cells = cols.cell is not None
     needs_obs = getattr(policy_fn, "needs_obs", True)
     needs_ctx = getattr(policy_fn, "needs_ctx", False)
     # the builtin argmins can only land on an invisible server when the
@@ -1152,7 +1173,7 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
     # speculative parallel commit: greedy only — its provisional argmin
     # depends on state only through (queue, residency), which the cheap
     # scan + drift replay reproduce exactly; other policies read obs/ctx
-    use_spec = speculative and policy_fn is _greedy_policy
+    use_spec = plan.speculative and policy_fn is _greedy_policy
     iota_n = jnp.arange(n, dtype=jnp.int32)
     num_k = params.size_bits.shape[0]
     iota_k = jnp.arange(num_k + 1, dtype=jnp.int32)  # +1: free-slot row
@@ -1165,15 +1186,9 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
     )                                                        # (K+1, N)
     carry = (lru, queue, clock, time_s)
 
-    def chunks(x):
-        return (
-            None if x is None else x.reshape((n_chunks, c) + x.shape[1:])
-        )
-
     def dense_commit(lru, queue, clock, model_b, gen_b, choice, ok):
-        """Dense one-hot LRU/queue commit at ``choice``, shared between
-        the correction scan and the speculative replay body: ONE column
-        slice yields hit bit, eviction candidates and capacity check."""
+        """Dense one-hot LRU/queue commit at ``choice``: ONE column slice
+        yields hit bit, eviction candidates and capacity check."""
         lru_col = jax.lax.dynamic_slice(
             lru, (jnp.int32(0), choice), (num_k + 1, 1)
         )[:, 0]
@@ -1201,22 +1216,20 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         return lru, queue, out_choice, hit
 
     def step(carry, xs):
+        """One request on live state: the correction scan's body, and the
+        speculative suffix replay's. ``xs`` is the request's row of
+        ``_Columns``, its row of the chunk's base panel, and its slice of
+        a chunk hook's table (or None). Returns the carry and ``(choice,
+        latency, hit, exact)``; ``exact`` is None without the hook."""
         lru, queue, clock, time_s = carry
-        model_b, scal_b, drain_b, arrival_b, valid_b, dl_b, base_b, \
-            prompt_b, cell_b, gctx_b, tloc_b, aux_b = xs
-        gen_b, size_b, ftok_b = scal_b[0], scal_b[1], scal_b[2]
-        # scal_b[0] is the COMMITTED gen (eta-scaled); policies see raw
-        gen_ctx = gen_b if gctx_b is None else gctx_b
+        row, base_b, aux_b = xs
+        # scal[0] is the COMMITTED gen (eta-scaled); policies see raw
+        gen_b, size_b, ftok_b = row.scal[0], row.scal[1], row.scal[2]
+        valid_b = row.valid
 
-        if has_time:  # wall-clock residue: queue decay since last arrival
-            dt = jnp.maximum(arrival_b - time_s, 0.0)
-            if valid_b is not None:
-                dt = jnp.where(valid_b, dt, 0.0)
-                time_s = jnp.where(valid_b,
-                                   jnp.maximum(time_s, arrival_b), time_s)
-            else:
-                time_s = jnp.maximum(time_s, arrival_b)
-            queue = jnp.maximum(queue - drain_rate * dt, 0.0)
+        if rate is not None:  # wall-clock residue: decay since last arrival
+            queue, time_s = advance_to_arrival(queue, time_s, row.arrival,
+                                               rate, valid_b)
         clock = clock + (1 if valid_b is None
                          else valid_b.astype(clock.dtype))
 
@@ -1226,7 +1239,7 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         # (N,)-constant expressions, so the whole chain fuses into one
         # elementwise kernel — no per-step (N,) input rows beyond base.
         rm_key = jax.lax.dynamic_slice(
-            lru, (model_b, jnp.int32(0)), (1, n)
+            lru, (row.model, jnp.int32(0)), (1, n)
         )[0]
         resident_m = rm_key < _LRU_FREE                         # (N,)
         lats = (
@@ -1234,23 +1247,17 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
             + jnp.where(resident_m, 0.0, size_b / params.backhaul_bps)
         ) + (queue * ftok_b) / params.flops_per_s
 
-        if needs_obs:
-            obs = jnp.stack(
-                [resident_m.astype(dtype), queue, params.flops_per_s], axis=-1
-            ).reshape(-1)
-        else:
-            obs = None
+        obs = (_observe(resident_m, queue, params.flops_per_s)
+               if needs_obs else None)
         queue_vis = queue
         if has_mask:
             # visibility/outage is already folded into base as +inf; XLA
             # DCEs this for policies that never read the queue (greedy)
             queue_vis = jnp.where(jnp.isfinite(base_b), queue, jnp.inf)
+        exact_b = None
         if needs_ctx:
-            ctx = PolicyCtx(
-                params=params, model=model_b, prompt_bits=prompt_b,
-                gen_tokens=gen_ctx, flops_tok=ftok_b, resident=resident_m,
-                queue=queue, cell=cell_b,
-            )
+            ctx = PolicyCtx(params=params, resident=resident_m, queue=queue,
+                            **_policy_view(row))
             if aux_b is not None:
                 # chunk-level hook: the per-chunk precompute already did
                 # the batched work; the per-step call only resolves the
@@ -1268,52 +1275,38 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         else:
             choice = jnp.asarray(policy_fn(lats, obs, queue_vis), jnp.int32)
         if needs_clamp:
-            # an actor may ignore the inf-masked inputs or return an
-            # index outside [0, N) — which a JAX gather would silently
-            # clamp to server N-1. Never commit an out-of-cell or
-            # out-of-range choice: fall back to the masked greedy argmin.
-            safe = jnp.clip(choice, 0, n - 1)
-            choice_ok = choice == safe
-            if has_mask:
-                # lats (not base) finiteness: covers the beta-poisoned
-                # switch residue too; pre-beta identical to base's
-                choice_ok &= jnp.isfinite(lats[safe])
-            choice = jnp.where(choice_ok, safe,
-                               jnp.argmin(lats).astype(jnp.int32))
+            choice = _clamped(choice, lats, has_mask)
 
         lat_b = lats[choice]
-        if tloc_b is not None:  # reported latency is eq. 13's max
-            lat_b = jnp.maximum(tloc_b, lat_b)
+        if row.tloc is not None:  # reported latency is eq. 13's max
+            lat_b = jnp.maximum(row.tloc, lat_b)
         ok = jnp.isfinite(lat_b) if has_mask else None
-        if dl_b is not None:  # SLO admission: best score vs deadline
-            best = jnp.min(lats)
-            if tloc_b is not None:
-                best = jnp.maximum(tloc_b, best)
-            admit = best <= dl_b
+        if row.deadline is not None:  # SLO admission: best score vs deadline
+            best = jnp.min(lats)  # greedy: lats[choice], the replay's case
+            if row.tloc is not None:
+                best = jnp.maximum(row.tloc, best)
+            admit = best <= row.deadline
             ok = admit if ok is None else ok & admit
         if valid_b is not None:
             ok = valid_b if ok is None else ok & valid_b
 
         # dense one-hot commit on the (K+1, N) lru encoding
         lru, queue, out_choice, hit = dense_commit(
-            lru, queue, clock, model_b, gen_b, choice, ok
+            lru, queue, clock, row.model, gen_b, choice, ok
         )
-        # one stacked output vector -> one scan write per request
-        cols = [out_choice.astype(dtype), lat_b, hit.astype(dtype)]
-        if needs_ctx and aux_b is not None:
-            cols.append(exact_b.astype(dtype))
-        out = jnp.stack(cols)
-        if drain_b is not None:
-            d = drain_b if valid_b is None else jnp.where(valid_b, drain_b,
-                                                          0.0)
-            if outage is not None:  # frozen queue on outaged servers
-                d = jnp.where(outage, 0.0, d)
-            queue = jnp.maximum(queue - d, 0.0)
-        return (lru, queue, clock, time_s), out
+        if row.drain is not None:
+            queue = drain_after_commit(queue, row.drain, valid_b, outage)
+        return (lru, queue, clock, time_s), (out_choice, lat_b, hit, exact_b)
 
-    def chunk_step(carry, xs):
-        model_c, scal_c, prompt_c, work_c, drain_c, cell_c, arr_c, \
-            valid_c, dl_c, praw_c, graw_c, tloc_c = xs
+    def scan_step(carry, xs):
+        carry, (choice, lat, hit, exact) = step(carry, xs)
+        # one stacked output vector -> one scan write per request
+        outs = [choice.astype(dtype), lat, hit.astype(dtype)]
+        if exact is not None:
+            outs.append(exact.astype(dtype))
+        return carry, jnp.stack(outs)
+
+    def score(cols_c):
         # phase 1 — ONE fused kernel call scores the whole chunk: the
         # switch-free base (eq. 5 + zero-backlog eq. 9) with the cell
         # mask (incl. spill surcharge) folded in as +inf. Everything
@@ -1323,27 +1316,23 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         # latencies) — the scan re-gates it.
         with jax.named_scope("route.score"):
             base = ops.route_score(
-                prompt_c, None, scal_c[:, 2], work_c,
+                cols_c.prompt, None, cols_c.scal[:, 2], cols_c.work,
                 params.uplink_bps, params.backhaul_bps, params.flops_per_s,
-                req_cell=cell_c,
+                req_cell=cols_c.cell,
                 srv_cell=params.cell if has_cells else None,
                 spill=params.spill if has_cells else None,
-                cloud_cell=CLOUD_CELL, backend=backend,
+                cloud_cell=CLOUD_CELL, backend=plan.backend,
             )                                                   # (c, N)
             if outage is not None:
                 base = jnp.where(outage[None, :], jnp.inf, base)
+        return base
 
-        def inner_xs(aux):
-            prompt_ctx = prompt_c if praw_c is None else praw_c
-            return (model_c, scal_c, drain_c, arr_c, valid_c, dl_c, base,
-                    prompt_ctx if needs_ctx else None,
-                    cell_c if needs_ctx and has_cells else None,
-                    graw_c if needs_ctx else None, tloc_c, aux)
-
+    def chunk_step(carry, cols_c):
+        base = score(cols_c)
         if not has_hook:
             with jax.named_scope("route.commit_scan"):
-                return jax.lax.scan(step, carry, inner_xs(None),
-                                    unroll=min(unroll, c))
+                return jax.lax.scan(scan_step, carry, (cols_c, base, None),
+                                    unroll=min(plan.unroll, c))
         # chunk-level policy hook: batch the expensive per-request work
         # (e.g. the actor MLP) over the whole chunk against the
         # CHUNK-ENTRY residency; the scan resolves each step against
@@ -1353,54 +1342,39 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         # existing (its captured operands defeat the scan-body fusion),
         # while a chunk that never drifts past the precomputed variants
         # pays only one predicate for the whole chunk.
-        cctx = ChunkPolicyCtx(
-            params=params,
-            model=model_c,
-            prompt_bits=prompt_c if praw_c is None else praw_c,
-            gen_tokens=scal_c[:, 0] if graw_c is None else graw_c,
-            flops_tok=scal_c[:, 2],
-            resident=(carry[0][:num_k] < _LRU_FREE).T,
-            cell=cell_c if has_cells else None,
-        )
-        aux = policy_fn.chunk_precompute(cctx)
+        aux = policy_fn.chunk_precompute(ChunkPolicyCtx(
+            params=params, resident=(carry[0][:num_k] < _LRU_FREE).T,
+            **_policy_view(cols_c)))
         with jax.named_scope("route.commit_scan"):
             fast_carry, fast_outs = jax.lax.scan(
-                step, carry, inner_xs(aux), unroll=min(unroll, c))
+                scan_step, carry, (cols_c, base, aux),
+                unroll=min(plan.unroll, c))
 
             def keep(_):
                 return fast_carry, fast_outs[:, :3]
 
             def replay(_):  # rerun the chunk through the per-request path
-                return jax.lax.scan(step, carry, inner_xs(None),
-                                    unroll=min(unroll, c))
+                return jax.lax.scan(scan_step, carry, (cols_c, base, None),
+                                    unroll=min(plan.unroll, c))
 
             return jax.lax.cond(jnp.all(fast_outs[:, 3] != 0.0),
                                 keep, replay, None)
 
-    def spec_chunk_step(carry, xs):
+    def spec_chunk_step(carry, cols_c):
         lru, queue, clock, time_s = carry
-        model_c, scal_c, prompt_c, work_c, drain_c, cell_c, arr_c, \
-            valid_c, dl_c, praw_c, graw_c, tloc_c = xs
-        gen_c, size_c, ftok_c = scal_c[:, 0], scal_c[:, 1], scal_c[:, 2]
+        gen_c, size_c, ftok_c = (cols_c.scal[:, 0], cols_c.scal[:, 1],
+                                 cols_c.scal[:, 2])
+        valid_c, tloc_c = cols_c.valid, cols_c.tloc
         idx_c = jnp.arange(c, dtype=jnp.int32)
 
         # phase 1 — the same switch-free base the correction scan uses...
+        base = score(cols_c)
         with jax.named_scope("route.score"):
-            base = ops.route_score(
-                prompt_c, None, ftok_c, work_c,
-                params.uplink_bps, params.backhaul_bps, params.flops_per_s,
-                req_cell=cell_c,
-                srv_cell=params.cell if has_cells else None,
-                spill=params.spill if has_cells else None,
-                cloud_cell=CLOUD_CELL, backend=backend,
-            )                                                    # (c, N)
-            if outage is not None:
-                base = jnp.where(outage[None, :], jnp.inf, base)
             # ... plus the eq. 7 switch gate priced against the CHUNK-ENTRY
             # residency, applied with the per-step expression verbatim: the
             # speculative scores stay bitwise equal to the correction
             # scan's on every step where residency has not yet drifted
-            hitrow = (lru[:num_k] < _LRU_FREE)[model_c]          # (c, N)
+            hitrow = (lru[:num_k] < _LRU_FREE)[cols_c.model]     # (c, N)
             basez = base + jnp.where(
                 hitrow, 0.0, size_c[:, None] / params.backhaul_bps[None, :]
             )
@@ -1411,9 +1385,10 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
         with jax.named_scope("route.spec_scan"):
             q_ext, choices, lat, t_ext = ops.route_spec_scan(
                 basez, ftok_c, gen_c, queue, time_s, params.flops_per_s,
-                drain_rate=drain_rate, arrival=arr_c, drain=drain_c,
-                outage=outage, valid=valid_c, deadline=dl_c, tloc=tloc_c,
-                has_mask=has_mask, unroll=unroll, backend=backend,
+                drain_rate=rate, arrival=cols_c.arrival, drain=cols_c.drain,
+                outage=outage, valid=valid_c, deadline=cols_c.deadline,
+                tloc=tloc_c, has_mask=has_mask, unroll=plan.unroll,
+                backend=plan.backend,
             )                                           # q_ext: (c+1, N)
         with jax.named_scope("route.rederive"):
             # `lat` is the score every commit gate compared, on every
@@ -1423,8 +1398,8 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
                 lat = jnp.maximum(tloc_c, lat)
             hits = jnp.take_along_axis(hitrow, col, axis=1)[:, 0]
             ok = jnp.isfinite(lat) if has_mask else jnp.ones((c,), bool)
-            if dl_c is not None:
-                ok &= lat <= dl_c
+            if cols_c.deadline is not None:
+                ok &= lat <= cols_c.deadline
             okv = ok if valid_c is None else ok & valid_c
             # first conflicting commit: a committed MISS mutates residency
             # (install + possible eviction), invalidating later frozen
@@ -1445,80 +1420,33 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
             in_prefix = okv & hits & (idx_c < i0)
             scat_col = jnp.where(in_prefix, choices, n)          # n: dump lane
             lru = jnp.pad(lru, ((0, 0), (0, 1)))
-            lru = lru.at[model_c, scat_col].max(clocks)[:, :n]
+            lru = lru.at[cols_c.model, scat_col].max(clocks)[:, :n]
             # rewind carried state to the first conflicting commit ...
             queue = jnp.take(q_ext, i0, axis=0)
             clock = clock + jnp.where(i0 > 0, cum[jnp.maximum(i0 - 1, 0)], 0)
-            if has_time:
+            if rate is not None:
                 time_s = jnp.take(t_ext, i0, axis=0)
             och = jnp.where(okv, choices, -1)
             ohit = hits & okv
 
         def replay_body(i, st):
-            # ... and replay the conflicting suffix serially with the
-            # full correction-scan body (live residency via the same
-            # expressions — bit-identical to the non-speculative path)
-            lru, queue, clk, ts, och, olat, ohit = st
-            model_b, gen_b = model_c[i], gen_c[i]
-            valid_b = None if valid_c is None else valid_c[i]
-            if has_time:
-                arrival_b = arr_c[i]
-                dt = jnp.maximum(arrival_b - ts, 0.0)
-                if valid_b is not None:
-                    dt = jnp.where(valid_b, dt, 0.0)
-                    ts = jnp.where(valid_b, jnp.maximum(ts, arrival_b), ts)
-                else:
-                    ts = jnp.maximum(ts, arrival_b)
-                queue = jnp.maximum(queue - drain_rate * dt, 0.0)
-            clk = clk + (1 if valid_b is None else valid_b.astype(clk.dtype))
-            rm_key = jax.lax.dynamic_slice(
-                lru, (model_b, jnp.int32(0)), (1, n)
-            )[0]
-            resident_m = rm_key < _LRU_FREE
-            lats = (
-                base[i]
-                + jnp.where(resident_m, 0.0,
-                            size_c[i] / params.backhaul_bps)
-            ) + (queue * ftok_c[i]) / params.flops_per_s
-            choice = jnp.argmin(lats).astype(jnp.int32)
-            lat_b = lats[choice]
-            if tloc_c is not None:  # eq. 13 max, matching the scan body
-                lat_b = jnp.maximum(tloc_c[i], lat_b)
-            ok_b = jnp.isfinite(lat_b) if has_mask else None
-            if dl_c is not None:  # greedy: lats[choice] == min(lats)
-                admit = lat_b <= dl_c[i]
-                ok_b = admit if ok_b is None else ok_b & admit
-            if valid_b is not None:
-                ok_b = valid_b if ok_b is None else ok_b & valid_b
-            lru, queue, out_choice, hit_b = dense_commit(
-                lru, queue, clk, model_b, gen_b, choice, ok_b
-            )
-            if drain_c is not None:
-                d = drain_c[i]
-                if valid_b is not None:
-                    d = jnp.where(valid_b, d, 0.0)
-                if outage is not None:
-                    d = jnp.where(outage, 0.0, d)
-                queue = jnp.maximum(queue - d, 0.0)
-            och = och.at[i].set(out_choice)
-            olat = olat.at[i].set(lat_b)
-            ohit = ohit.at[i].set(hit_b)
-            return (lru, queue, clk, ts, och, olat, ohit)
+            # ... and replay the conflicting suffix serially through the
+            # correction scan's own step, on live residency
+            carry, outs = st
+            # the strip is read column by column, as everywhere in this
+            # chunk: a row read would change the strip's layout
+            row = jax.tree.map(lambda x: x[i], cols_c._replace(scal=None))
+            row = row._replace(
+                scal=jnp.stack([gen_c[i], size_c[i], ftok_c[i]]))
+            carry, got = step(carry, (row, base[i], None))
+            return carry, tuple(o.at[i].set(g) for o, g in zip(outs, got))
 
-        st = (lru, queue, clock, time_s, och, lat, ohit)
         with jax.named_scope("route.replay"):
-            lru, queue, clock, time_s, och, olat, ohit = jax.lax.fori_loop(
-                i0, c, replay_body, st
-            )
-        return (lru, queue, clock, time_s), (och, olat, ohit)
+            return jax.lax.fori_loop(
+                i0, c, replay_body,
+                ((lru, queue, clock, time_s), (och, lat, ohit)))
 
-    # (c, 3) strip of per-request scalars: one xs slice per step.
-    # Column 0 is the COMMITTED gen (eta-scaled when the knob is set);
-    # the raw columns ride separately for policy ctx only.
-    scalars = jnp.stack([gen_commit, size_bits, flops_tok], axis=1)
-    xs = tuple(map(chunks, (model, scalars, prompt_eff, work,
-                            drains, cells, arrs, valid, dls,
-                            praw, graw, tloc)))
+    xs = jax.tree.map(lambda x: x.reshape((b // c, c) + x.shape[1:]), cols)
     carry, outs = jax.lax.scan(spec_chunk_step if use_spec else chunk_step,
                                carry, xs)
     lru, queue, clock, time_s = carry
@@ -1529,15 +1457,9 @@ def _scan_chunked(params, reqs, carry, policy_fn, dtype, gen_tokens, drain,
     last_use = jnp.where(resident, lru.T, last_use0)
     carry = (resident, last_use, queue, clock, time_s)
     if use_spec:                                             # unpack
-        choice = outs[0].reshape(n_chunks * c)[:b]
-        latency = outs[1].reshape(n_chunks * c)[:b]
-        hit = outs[2].reshape(n_chunks * c)[:b]
-    else:
-        outs = outs.reshape(n_chunks * c, 3)[:b]
-        choice = outs[:, 0].astype(jnp.int32)
-        latency = outs[:, 1]
-        hit = outs[:, 2] != 0
-    return carry, (choice, latency, hit)
+        return carry, tuple(o.reshape(b) for o in outs)
+    outs = outs.reshape(b, 3)
+    return carry, (outs[:, 0].astype(jnp.int32), outs[:, 1], outs[:, 2] != 0)
 
 
 def stats(outcome: RouteOutcome, *, cloud_index: Optional[int] = None) -> dict:

@@ -133,6 +133,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import batch_router as br
+from repro.core.queue import advance_to_arrival
 from repro.core.router import CLOUD_CELL
 from repro.distributed import sharding
 
@@ -171,7 +172,8 @@ def _bucket_requests(reqs: br.RequestBatch, layout: br.CellLayout,
                      keep_cells: bool = False):
     """Host-side bucketing of a (B,) request stream into dense
     ``(c_pad, bc)`` per-cell buckets (numpy; the result feeds the jitted
-    mesh call).
+    mesh call). Returns ``(buckets, gpos)``: a ``RequestBatch`` of
+    ``(c_pad, bc)`` columns, and the bucket slots' stream positions.
 
     Real requests keep their arrival order inside their cell's bucket
     and carry inner cell 0; orphans (out-of-range ``cell``) are spread
@@ -190,9 +192,6 @@ def _bucket_requests(reqs: br.RequestBatch, layout: br.CellLayout,
     clock remap both key off it."""
     c = layout.num_cells
     b = int(reqs.model.shape[0])
-    model = np.asarray(reqs.model)
-    prompt = np.asarray(reqs.prompt_bits)
-    gen = np.asarray(reqs.gen_tokens)
     if reqs.cell is not None:
         rcell = np.asarray(reqs.cell).astype(np.int64)
     else:
@@ -207,50 +206,37 @@ def _bucket_requests(reqs: br.RequestBatch, layout: br.CellLayout,
     sortedb = bucket[order]
     slot = np.arange(b, dtype=np.int64) - starts[sortedb]
 
+    def bucketed(col, fill, dtype=None):
+        """``col`` in bucket slots, padding rows ``fill``."""
+        col = np.asarray(col, dtype)
+        out = np.full((c_pad, bc), fill, col.dtype)
+        out[sortedb, slot] = col[order]
+        return out
+
     gpos = np.full((c_pad, bc), -1, np.int32)
-    model_b = np.zeros((c_pad, bc), model.dtype)
-    prompt_b = np.full((c_pad, bc), np.inf, prompt.dtype)
-    gen_b = np.zeros((c_pad, bc), gen.dtype)
-    icell_b = np.full((c_pad, bc), _ORPHAN_CELL, np.int32)
     gpos[sortedb, slot] = order
-    model_b[sortedb, slot] = model[order]
-    prompt_b[sortedb, slot] = prompt[order]
-    gen_b[sortedb, slot] = gen[order]
     if keep_cells:
-        icell_b[sortedb, slot] = rcell[order].astype(np.int32)
+        icell = rcell.astype(np.int32)
     else:
-        icell_b[sortedb, slot] = np.where(in_range[order], 0, _ORPHAN_CELL)
-
-    dl_b = None
-    if reqs.deadline_s is not None:
-        dl = np.asarray(reqs.deadline_s)
-        dl_b = np.full((c_pad, bc), np.inf, dl.dtype)
-        dl_b[sortedb, slot] = dl[order]
-
+        icell = np.where(in_range, 0, _ORPHAN_CELL).astype(np.int32)
     # eq. 16 action columns: padding carries eta = 1 (the +inf prompt
     # pad must not multiply to NaN), beta = True and a zero local rate
     # (t_local is where-guarded on local > 0) — all inert
-    eta_b = None
-    if reqs.eta is not None:
-        eta = np.asarray(reqs.eta)
-        eta_b = np.ones((c_pad, bc), eta.dtype)
-        eta_b[sortedb, slot] = eta[order]
-    beta_b = None
-    if reqs.beta is not None:
-        beta = np.asarray(reqs.beta, bool)
-        beta_b = np.ones((c_pad, bc), bool)
-        beta_b[sortedb, slot] = beta[order]
-    loc_b = None
-    if reqs.eta is not None and reqs.local_flops_per_s is not None:
-        loc = np.asarray(reqs.local_flops_per_s)
-        loc_b = np.zeros((c_pad, bc), loc.dtype)
-        loc_b[sortedb, slot] = loc[order]
-
-    arr_b = None
+    has_local = reqs.eta is not None and reqs.local_flops_per_s is not None
+    buckets = br.RequestBatch(
+        model=bucketed(reqs.model, 0),
+        prompt_bits=bucketed(reqs.prompt_bits, np.inf),
+        gen_tokens=bucketed(reqs.gen_tokens, 0),
+        cell=bucketed(icell, _ORPHAN_CELL),
+        deadline_s=(None if reqs.deadline_s is None
+                    else bucketed(reqs.deadline_s, np.inf)),
+        eta=None if reqs.eta is None else bucketed(reqs.eta, 1),
+        beta=None if reqs.beta is None else bucketed(reqs.beta, True, bool),
+        local_flops_per_s=(bucketed(reqs.local_flops_per_s, 0)
+                           if has_local else None),
+    )
     if has_time:
         arr = np.asarray(reqs.arrival_s)
-        arr_b = np.zeros((c_pad, bc), arr.dtype)
-        arr_b[sortedb, slot] = arr[order]
         # padding arrivals: the bucket's latest stamp (or the fleet
         # clock) — never ahead of the inner running time, so dt == 0
         bmax = np.full(c_pad, time0, arr.dtype)
@@ -259,32 +245,44 @@ def _bucket_requests(reqs: br.RequestBatch, layout: br.CellLayout,
         pad_counts = np.zeros(c_pad, np.int64)
         pad_counts[:c] = counts
         padmask = np.arange(bc)[None, :] >= pad_counts[:, None]
-        arr_b = np.where(padmask, bmax[:, None], arr_b)
-    return (model_b, prompt_b, gen_b, icell_b, arr_b, dl_b, eta_b, beta_b,
-            loc_b, gpos)
+        buckets = buckets._replace(arrival_s=np.where(
+            padmask, bmax[:, None], bucketed(arr, 0)))
+    return buckets, gpos
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("mesh", "axis", "layout", "c_pad", "policy", "actor",
-                     "chunk", "unroll", "backend", "speculative"),
-)
-def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
-                   dl_b, eta_b, beta_b, loc_b, outage, gpos_b, gen_g, arr_g,
-                   eta_g, *, mesh, axis, layout,
-                   c_pad, policy, actor, chunk, unroll, backend, speculative):
-    policy_fn = br._resolve_policy(policy, actor)
+def _scatter_outcome(gpos_b, ch, lat, hit, b, dtype):
+    """Per-bucket ``(choice, latency, hit)`` back in stream order: bucket
+    slot ``s`` lands at ``gpos_b[s]``; padding slots (-1) are dropped."""
+    gposf = gpos_b.reshape(-1)
+    safe = jnp.where(gposf >= 0, gposf, b)  # b: out of bounds -> dropped
+    choice = jnp.zeros((b,), jnp.int32).at[safe].set(ch.reshape(-1),
+                                                      mode="drop")
+    latency = jnp.zeros((b,), dtype).at[safe].set(
+        lat.reshape(-1).astype(dtype), mode="drop")
+    hit = jnp.zeros((b,), bool).at[safe].set(hit.reshape(-1), mode="drop")
+    return choice, latency, hit
+
+
+def _committed_tokens(reqs, dtype):
+    """The tokens each request of the stream commits: a partial offload
+    commits eta * gen_tokens (one exact-rounded multiply — the same bits
+    every per-cell scan folded)."""
+    gen = reqs.gen_tokens.astype(dtype)
+    return gen if reqs.eta is None else gen * reqs.eta.astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "layout", "plan"))
+def _sharded_route(params, state, bucketed, reqs, outage, *, mesh, layout,
+                   plan):
+    buckets, gpos_b = bucketed  # _bucket_requests' pair
+    axis = mesh.axis_names[0]
     c, n, nc = layout.num_cells, layout.per_cell, layout.num_cloud
+    c_pad, bc = buckets.model.shape
     ne, m = layout.num_edge, layout.per_cell + layout.num_cloud
-    bc = int(model_b.shape[1])
-    b = int(gen_g.shape[0])
-    dtype = jnp.result_type(prompt_b, params.uplink_bps)
-    has_time = params.drain_rate is not None and arr_b is not None
-    has_dl = dl_b is not None
-    has_eta = eta_b is not None
-    has_beta = beta_b is not None
-    has_loc = loc_b is not None
-    has_outage = outage is not None
+    b = int(reqs.model.shape[0])
+    dtype = jnp.result_type(buckets.prompt_bits, params.uplink_bps)
+    has_time = (params.drain_rate is not None
+                and buckets.arrival_s is not None)
     clock0 = state.clock
     time0 = jnp.asarray(
         state.time_s if state.time_s is not None else 0.0, dtype
@@ -295,6 +293,8 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
         """(N, ...) server-major -> (c_pad, n+nc, ...) cell blocks, the
         cloud rows replicated into every block, padded cells inert
         copies of block 0 (their requests are all padding)."""
+        if x is None:
+            return None
         blk = x[:ne].reshape((c, n) + x.shape[1:])
         if nc:
             cloud = jnp.broadcast_to(x[ne:][None], (c, nc) + x.shape[1:])
@@ -310,59 +310,31 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
         jnp.full((nc,), CLOUD_CELL, jnp.int32),
     ]) if nc else jnp.zeros((n,), jnp.int32)
 
-    has_drain = params.drain_rate is not None
-    ins = [
-        blocks(params.flops_per_s), blocks(params.uplink_bps),
-        blocks(params.backhaul_bps), blocks(params.cache_slots),
-        blocks(state.resident), blocks(state.last_use), blocks(queue0),
-        model_b, prompt_b, gen_b, icell_b, gpos_b,
-    ]
-    if has_drain:
-        ins.append(blocks(params.drain_rate))
-    if has_time:
-        ins.append(arr_b)
-    if has_dl:
-        ins.append(dl_b)
-    if has_eta:
-        ins.append(eta_b)
-    if has_beta:
-        ins.append(beta_b)
-    if has_loc:
-        ins.append(loc_b)
-    if has_outage:
-        ins.append(blocks(outage))
-    n_shard = len(ins)
-    repl = [params.size_bits, params.decode_flops_per_token, clock0, time0,
-            local_cell]
+    # per-block server columns (the shared ones ride replicated)
+    blk_params = params._replace(
+        flops_per_s=blocks(params.flops_per_s),
+        uplink_bps=blocks(params.uplink_bps),
+        backhaul_bps=blocks(params.backhaul_bps),
+        cache_slots=blocks(params.cache_slots),
+        size_bits=None, decode_flops_per_token=None, cell=None,
+        drain_rate=blocks(params.drain_rate), spill=None,
+    )
+    blk_state = br.FleetState(resident=blocks(state.resident),
+                              last_use=blocks(state.last_use),
+                              queue_tokens=blocks(queue0), clock=None)
+    sharded = (blk_params, blk_state, buckets, gpos_b, blocks(outage))
+    repl = (params.size_bits, params.decode_flops_per_token, clock0, time0,
+            local_cell)
 
-    def device_fn(*args):
-        sh = args[:n_shard]
-        size_bits, dflops, clk0, t0, lcell = args[n_shard:]
+    def device_fn(sharded, repl):
+        size_bits, dflops, clk0, t0, lcell = repl
 
         def one_cell(cell_args):
-            (fl, up, bh, slots, res, lu, q, mdl, pr, gn, icl,
-             gp, *rest) = cell_args
-            rest = list(rest)
-            dr = rest.pop(0) if has_drain else None
-            ar = rest.pop(0) if has_time else None
-            dl = rest.pop(0) if has_dl else None
-            et = rest.pop(0) if has_eta else None
-            bt = rest.pop(0) if has_beta else None
-            lc = rest.pop(0) if has_loc else None
-            og = rest.pop(0) if has_outage else None
-            p = br.FleetParams(
-                flops_per_s=fl, uplink_bps=up, backhaul_bps=bh,
-                cache_slots=slots, size_bits=size_bits,
-                decode_flops_per_token=dflops, cell=lcell, drain_rate=dr,
-            )
-            s = br.FleetState(resident=res, last_use=lu, queue_tokens=q,
-                              clock=clk0, time_s=t0)
-            r = br.RequestBatch(model=mdl, prompt_bits=pr, gen_tokens=gn,
-                                cell=icl, arrival_s=ar, deadline_s=dl,
-                                eta=et, beta=bt, local_flops_per_s=lc)
-            st, out = br._route_core(p, s, r, None, policy_fn, chunk=chunk,
-                                     unroll=unroll, backend=backend,
-                                     speculative=speculative, outage=og)
+            bp, bs, r, gp, og = cell_args
+            p = bp._replace(size_bits=size_bits,
+                            decode_flops_per_token=dflops, cell=lcell)
+            s = bs._replace(clock=clk0, time_s=t0)
+            st, out = br._route_core(p, s, r, None, og, plan)
             # local -> global LRU clock remap: commits from THIS window
             # (> clock0 — stale entries, including pre-window values,
             # never exceed the entry clock) are rewritten to clock0 + 1
@@ -376,13 +348,12 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
             return (st.resident, lu2, st.queue_tokens.astype(dtype),
                     out.choice, out.latency, out.hit)
 
-        return jax.vmap(one_cell)(sh)
+        return jax.vmap(one_cell)(sharded)
 
     routed = jax.shard_map(
-        device_fn, mesh=mesh,
-        in_specs=(P(axis),) * n_shard + (P(),) * len(repl),
-        out_specs=(P(axis),) * 6, check_vma=False,
-    )(*ins, *repl)
+        device_fn, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
+        check_vma=False,
+    )(sharded, repl)
     res_o, lu_o, q_o, ch_o, lat_o, hit_o = routed
 
     # --- reassemble the cell-major fleet state (real cells only) ---
@@ -405,13 +376,8 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
         jnp.take_along_axis(imap, jnp.clip(ch_o, 0, m - 1), axis=1),
         -1,
     )
-    gposf = gpos_b.reshape(-1)
-    safe = jnp.where(gposf >= 0, gposf, b)  # b: out of bounds -> dropped
-    choice = jnp.zeros((b,), jnp.int32).at[safe].set(
-        ch_glob.reshape(-1), mode="drop")
-    latency = jnp.zeros((b,), dtype).at[safe].set(
-        lat_o.reshape(-1).astype(dtype), mode="drop")
-    hit = jnp.zeros((b,), bool).at[safe].set(hit_o.reshape(-1), mode="drop")
+    choice, latency, hit = _scatter_outcome(gpos_b, ch_glob, lat_o, hit_o,
+                                            b, dtype)
 
     # --- cloud reconciliation ---
     if nc:
@@ -428,38 +394,28 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
         cloud_ids = ne + jnp.arange(nc, dtype=jnp.int32)
         rate_cloud = (params.drain_rate[ne:].astype(dtype)
                       if has_time else None)
-        if has_time and has_outage:
+        if has_time and outage is not None:
             # frozen queue: an outaged cloud column stops draining, in
             # the replay exactly as in every per-cell scan
             rate_cloud = jnp.where(outage[ne:], 0.0, rate_cloud)
 
         def replay_step(carry, xs):
             qc, trun = carry
+            ch_i, g_i, a_i = xs
             if has_time:
-                ch_i, g_i, a_i = xs
-                dt = jnp.maximum(a_i - trun, 0.0)
-                trun = jnp.maximum(trun, a_i)
-                qc = jnp.maximum(qc - rate_cloud * dt, 0.0)
-            else:
-                ch_i, g_i = xs
+                qc, trun = advance_to_arrival(qc, trun, a_i, rate_cloud)
             qc = qc + jnp.where(cloud_ids == ch_i, g_i, 0.0)
             return (qc, trun), None
 
-        # a partial offload commits eta * gen_tokens (one exact-rounded
-        # multiply — the same bits every per-cell scan folded)
-        gen_rep = gen_g.astype(dtype)
-        if eta_g is not None:
-            gen_rep = gen_rep * eta_g.astype(dtype)
-        xs = (choice, gen_rep)
-        if has_time:
-            xs += (arr_g.astype(dtype),)
+        xs = (choice, _committed_tokens(reqs, dtype),
+              reqs.arrival_s.astype(dtype) if has_time else None)
         (q_cloud, _), _ = jax.lax.scan(
             replay_step, (queue0[ne:], time0), xs, unroll=min(64, b))
         queue = jnp.concatenate([queue, q_cloud])
 
     clock_f = clock0 + jnp.asarray(b, clock0.dtype)
     if has_time:
-        time_f = jnp.maximum(time0, jnp.max(arr_g.astype(dtype)))
+        time_f = jnp.maximum(time0, jnp.max(reqs.arrival_s.astype(dtype)))
     else:
         time_f = time0
     new_state = br.FleetState(resident=resident, last_use=last_use,
@@ -469,16 +425,9 @@ def _sharded_route(params, state, model_b, prompt_b, gen_b, icell_b, arr_b,
                                       hit=hit)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("mesh", "axis", "c_pad", "policy", "actor", "chunk",
-                     "unroll", "backend", "speculative"),
-)
-def _sharded_route_spill(params, state, model_b, prompt_b, gen_b, icell_b,
-                         arr_b, dl_b, eta_b, beta_b, loc_b, outage, gpos_b,
-                         model_g, gen_g, arr_g, eta_g,
-                         *, mesh, axis, c_pad, policy, actor, chunk, unroll,
-                         backend, speculative):
+@functools.partial(jax.jit, static_argnames=("mesh", "plan"))
+def _sharded_route_spill(params, state, bucketed, reqs, outage, *, mesh,
+                         plan):
     """Full-replication sharded route for spill fleets (module docstring:
     robustness knobs). Every device row holds the WHOLE fleet; each cell
     bucket routes against the window-entry snapshot with the GLOBAL
@@ -487,121 +436,60 @@ def _sharded_route_spill(params, state, model_b, prompt_b, gen_b, icell_b,
     carried state is rebuilt by one close-replay scan over the committed
     choices in global arrival order: the exact ``batch_router._commit``
     fold, wall-clock decay and outage freeze included."""
-    policy_fn = br._resolve_policy(policy, actor)
-    b = int(model_g.shape[0])
-    dtype = jnp.result_type(prompt_b, params.uplink_bps)
-    has_time = params.drain_rate is not None and arr_b is not None
-    has_dl = dl_b is not None
-    has_eta = eta_b is not None
-    has_beta = beta_b is not None
-    has_loc = loc_b is not None
-    has_outage = outage is not None
+    buckets, gpos_b = bucketed  # _bucket_requests' pair
+    axis = mesh.axis_names[0]
+    b = int(reqs.model.shape[0])
+    dtype = jnp.result_type(buckets.prompt_bits, params.uplink_bps)
+    has_time = (params.drain_rate is not None
+                and buckets.arrival_s is not None)
     clock0 = state.clock
     time0 = jnp.asarray(
         state.time_s if state.time_s is not None else 0.0, dtype
     )
     queue0 = state.queue_tokens.astype(dtype)
 
-    sharded = [model_b, prompt_b, gen_b, icell_b]
-    if has_time:
-        sharded.append(arr_b)
-    if has_dl:
-        sharded.append(dl_b)
-    if has_eta:
-        sharded.append(eta_b)
-    if has_beta:
-        sharded.append(beta_b)
-    if has_loc:
-        sharded.append(loc_b)
-    n_shard = len(sharded)
-    repl = [params, state] + ([outage] if has_outage else [])
+    def device_fn(buckets, full):
+        p_full, s_full, og = full
 
-    def device_fn(*args):
-        sh = args[:n_shard]
-        p_full, s_full = args[n_shard], args[n_shard + 1]
-        og = args[n_shard + 2] if has_outage else None
-
-        def one_bucket(cell_args):
-            mdl, pr, gn, icl, *rest = cell_args
-            rest = list(rest)
-            ar = rest.pop(0) if has_time else None
-            dl = rest.pop(0) if has_dl else None
-            et = rest.pop(0) if has_eta else None
-            bt = rest.pop(0) if has_beta else None
-            lc = rest.pop(0) if has_loc else None
-            r = br.RequestBatch(model=mdl, prompt_bits=pr, gen_tokens=gn,
-                                cell=icl, arrival_s=ar, deadline_s=dl,
-                                eta=et, beta=bt, local_flops_per_s=lc)
-            _, out = br._route_core(p_full, s_full, r, None, policy_fn,
-                                    chunk=chunk, unroll=unroll,
-                                    backend=backend, speculative=speculative,
-                                    outage=og)
+        def one_bucket(r):
+            _, out = br._route_core(p_full, s_full, r, None, og, plan)
             # per-bucket state is discarded: the close replay below is
             # the single source of truth for the carried fleet
             return out.choice, out.latency, out.hit
 
-        return jax.vmap(one_bucket)(sh)
+        return jax.vmap(one_bucket)(buckets)
 
     ch_o, lat_o, hit_o = jax.shard_map(
-        device_fn, mesh=mesh,
-        in_specs=(P(axis),) * n_shard + (P(),) * len(repl),
-        out_specs=(P(axis),) * 3, check_vma=False,
-    )(*sharded, *repl)
+        device_fn, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
+        check_vma=False,
+    )(buckets, (params, state, outage))
 
     # --- scatter outcomes back to the caller's stream order ---
-    gposf = gpos_b.reshape(-1)
-    safe = jnp.where(gposf >= 0, gposf, b)  # b: out of bounds -> dropped
-    choice = jnp.zeros((b,), jnp.int32).at[safe].set(
-        ch_o.reshape(-1), mode="drop")
-    latency = jnp.zeros((b,), dtype).at[safe].set(
-        lat_o.reshape(-1).astype(dtype), mode="drop")
-    hit = jnp.zeros((b,), bool).at[safe].set(hit_o.reshape(-1), mode="drop")
+    choice, latency, hit = _scatter_outcome(gpos_b, ch_o, lat_o, hit_o, b,
+                                            dtype)
 
     # --- close replay: sequential fold of the committed choices ---
     drain_rate = params.drain_rate.astype(dtype) if has_time else None
-    if drain_rate is not None and has_outage:
+    if drain_rate is not None and outage is not None:
         drain_rate = jnp.where(outage, 0.0, drain_rate)
     nsrv = int(params.flops_per_s.shape[0])
 
     def commit_step(carry, xs):
-        resident, last_use, queue, clock, time_s = carry
+        fleet, clock, time_s = carry
+        model, gen_i, ch_i, a_i = xs
+        queue = fleet[2]
         if has_time:
-            model, gen_i, ch_i, a_i = xs
-            dt = jnp.maximum(a_i - time_s, 0.0)
-            queue = jnp.maximum(queue - drain_rate * dt, 0.0)
-            time_s = jnp.maximum(time_s, a_i)
-        else:
-            model, gen_i, ch_i = xs
+            queue, time_s = advance_to_arrival(queue, time_s, a_i,
+                                               drain_rate)
         clock = clock + 1
-        ok = ch_i >= 0
-        sel = jnp.clip(ch_i, 0, nsrv - 1)
-        # _commit's ok-gated branch, expression for expression
-        row = resident[sel]
-        was_resident = row[model]
-        full = row.sum() >= params.cache_slots[sel]
-        evict_idx = jnp.argmin(
-            jnp.where(row, last_use[sel], jnp.iinfo(jnp.int32).max)
-        )
-        evict = ~was_resident & full & ok
-        row = row.at[evict_idx].set(row[evict_idx] & ~evict)
-        row = row.at[model].set(row[model] | ok)
-        resident = resident.at[sel].set(row)
-        last_use = last_use.at[sel, model].set(
-            jnp.where(ok, clock, last_use[sel, model])
-        )
-        queue = queue.at[sel].add(jnp.where(ok, gen_i, 0.0))
-        return (resident, last_use, queue, clock, time_s), None
+        fleet, _ = br._commit(params, fleet[:2] + (queue,), clock, model,
+                              gen_i, jnp.clip(ch_i, 0, nsrv - 1), ch_i >= 0)
+        return (fleet, clock, time_s), None
 
-    # a partial offload commits eta * gen_tokens — same bits as the
-    # per-cell scans (one exact-rounded multiply, see _sharded_route)
-    gen_rep = gen_g.astype(dtype)
-    if eta_g is not None:
-        gen_rep = gen_rep * eta_g.astype(dtype)
-    xs = (model_g, gen_rep, choice)
-    if has_time:
-        xs += (arr_g.astype(dtype),)
-    carry = (state.resident, state.last_use, queue0, clock0, time0)
-    (resident, last_use, queue, clock_f, time_f), _ = jax.lax.scan(
+    xs = (reqs.model, _committed_tokens(reqs, dtype), choice,
+          reqs.arrival_s.astype(dtype) if has_time else None)
+    carry = ((state.resident, state.last_use, queue0), clock0, time0)
+    ((resident, last_use, queue), clock_f, time_f), _ = jax.lax.scan(
         commit_step, carry, xs, unroll=min(64, b))
 
     new_state = br.FleetState(resident=resident, last_use=last_use,
@@ -663,7 +551,6 @@ def route_batch_sharded(
         mesh = cells_mesh(d)
     else:
         d = int(mesh.shape[mesh.axis_names[0]])
-    axis = mesh.axis_names[0]
 
     order = None
     try:
@@ -703,32 +590,15 @@ def route_batch_sharded(
     time0 = float(np.asarray(state.time_s)) if state.time_s is not None \
         else 0.0
     has_spill = params.spill is not None and params.cell is not None
-    (model_b, prompt_b, gen_b, icell_b, arr_b, dl_b, eta_b, beta_b, loc_b,
-     gpos) = _bucket_requests(
-        reqs, layout, c_pad, time0, has_time, keep_cells=has_spill)
-
-    route_fn = _sharded_route_spill if has_spill else _sharded_route
-    layout_kw = {} if has_spill else {"layout": layout}
-    first = (reqs.model,) if has_spill else ()
-    new_state, out = route_fn(
-        params, state,
-        jnp.asarray(model_b), jnp.asarray(prompt_b), jnp.asarray(gen_b),
-        jnp.asarray(icell_b),
-        None if arr_b is None else jnp.asarray(arr_b),
-        None if dl_b is None else jnp.asarray(dl_b),
-        None if eta_b is None else jnp.asarray(eta_b),
-        None if beta_b is None else jnp.asarray(beta_b),
-        None if loc_b is None else jnp.asarray(loc_b),
-        outage,
-        jnp.asarray(gpos),
-        *first,
-        reqs.gen_tokens,
-        reqs.arrival_s if has_time else None,
-        reqs.eta,
-        mesh=mesh, axis=axis, c_pad=c_pad, policy=policy,
-        actor=actor, chunk=chunk, unroll=unroll, backend=backend,
-        speculative=speculative, **layout_kw,
-    )
+    bucketed = jax.tree.map(jnp.asarray, _bucket_requests(
+        reqs, layout, c_pad, time0, has_time, keep_cells=has_spill))
+    plan = br._Plan(policy, actor, chunk, unroll, backend, speculative)
+    if has_spill:
+        new_state, out = _sharded_route_spill(params, state, bucketed, reqs,
+                                              outage, mesh=mesh, plan=plan)
+    else:
+        new_state, out = _sharded_route(params, state, bucketed, reqs, outage,
+                                        mesh=mesh, layout=layout, plan=plan)
     # the cause channel is a post-hoc pure function of visibility, the
     # outage mask and the scattered choices — shared with every other
     # path, so the sharded rates agree bitwise (docs/robustness.md)

@@ -11,10 +11,10 @@ Activation layout everywhere: (batch, seq, heads, head_dim).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
+
+from repro.core.queue import advance_to_arrival, drain_after_commit
 
 NEG_INF = -1e30
 
@@ -295,7 +295,8 @@ def spec_scan_xla(basez, ftok, gen, queue, time_s, flops_per_s, *,
     Residency (and with it the argmin's score ordering) is frozen at
     chunk entry, so only the queue backlog rides the carry: score,
     argmin, one masked add. Each step emits ``lats[choice]``, the score
-    its own gates compared, so the caller never re-derives it."""
+    its own gates compared, so the caller never re-derives it. The
+    queue moves through ``core.queue``, as in the kernel."""
     c, n = basez.shape
     iota_n = jnp.arange(n, dtype=jnp.int32)
     has_time = drain_rate is not None
@@ -305,14 +306,8 @@ def spec_scan_xla(basez, ftok, gen, queue, time_s, flops_per_s, *,
         basez_b, ftok_b, gen_b, drain_b, arrival_b, valid_b, dl_b, \
             tloc_b = xs_b
         if has_time:
-            dt = jnp.maximum(arrival_b - time_s, 0.0)
-            if valid_b is not None:
-                dt = jnp.where(valid_b, dt, 0.0)
-                time_s = jnp.where(valid_b,
-                                   jnp.maximum(time_s, arrival_b), time_s)
-            else:
-                time_s = jnp.maximum(time_s, arrival_b)
-            queue = jnp.maximum(queue - drain_rate * dt, 0.0)
+            queue, time_s = advance_to_arrival(queue, time_s, arrival_b,
+                                               drain_rate, valid_b)
         lats = basez_b + (queue * ftok_b) / flops_per_s
         choice = jnp.argmin(lats).astype(jnp.int32)
         lat = lats[choice]
@@ -329,11 +324,7 @@ def spec_scan_xla(basez, ftok, gen, queue, time_s, flops_per_s, *,
             touch_n &= valid_b
         queue = queue + jnp.where(touch_n, gen_b, 0.0)
         if drain_b is not None:
-            d = drain_b if valid_b is None else jnp.where(valid_b,
-                                                          drain_b, 0.0)
-            if outage is not None:
-                d = jnp.where(outage, 0.0, d)
-            queue = jnp.maximum(queue - d, 0.0)
+            queue = drain_after_commit(queue, drain_b, valid_b, outage)
         out = (choice, lat, queue) + ((time_s,) if has_time else ())
         return (queue, time_s), out
 

@@ -22,9 +22,11 @@ queues stay 0. The per-request scalars are (1, c) rows in SMEM, so the
 call batches under ``vmap`` (the sharded router maps it over cell
 blocks): Pallas then runs one grid step per batch row.
 
-The body evaluates ``kernels.ref.spec_scan_xla``'s expressions in the
-same order and precision. Its argmin is the row's min, then the min of
-``where(lats == m, lane, N_pad)``: ``jnp.argmin``'s lowest-index
+The body runs the step of ``kernels.ref.spec_scan_xla``: the queue
+moves through ``core.queue``'s two functions, applied to the values
+loaded from the refs, and the score and gates are the same expressions
+in the same order and precision. Its argmin is the row's min, then the
+min of ``where(lats == m, lane, N_pad)``: ``jnp.argmin``'s lowest-index
 tie-break, and lane 0 for a row that is all ``+inf``. Because ``lats >=
 basez`` lane by lane, the chosen ``basez`` is finite exactly when ``m``
 is, or, when ``m`` is ``+inf`` (the argmin is then lane 0), when
@@ -45,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.queue import advance_to_arrival, drain_after_commit
 
 _LANES = 128
 
@@ -96,15 +100,9 @@ def _kernel(*refs, c, has_mask, has_time, has_drain, has_outage, has_valid,
         queue, time_s = carry
         valid_b = valid_ref[0, i] != 0 if has_valid else None
         if has_time:
-            arrival_b = arrival_ref[0, i]
-            dt = jnp.maximum(arrival_b - time_s, 0.0)
-            if has_valid:
-                dt = jnp.where(valid_b, dt, 0.0)
-                time_s = jnp.where(valid_b, jnp.maximum(time_s, arrival_b),
-                                   time_s)
-            else:
-                time_s = jnp.maximum(time_s, arrival_b)
-            queue = jnp.maximum(queue - rate * dt, 0.0)
+            queue, time_s = advance_to_arrival(queue, time_s,
+                                               arrival_ref[0, i], rate,
+                                               valid_b)
         basez_b = basez_ref[i]
         lats = basez_b + (queue * ftok_ref[0, i]) / flops
         m = jnp.min(lats)
@@ -119,12 +117,8 @@ def _kernel(*refs, c, has_mask, has_time, has_drain, has_outage, has_valid,
             gate &= valid_b
         queue = queue + jnp.where((lane == choice) & gate, gen_ref[0, i], 0.0)
         if has_drain:
-            d = drain_ref[0, i]
-            if has_valid:
-                d = jnp.where(valid_b, d, 0.0)
-            if has_outage:
-                d = jnp.where(frozen, 0.0, d)
-            queue = jnp.maximum(queue - d, 0.0)
+            queue = drain_after_commit(queue, drain_ref[0, i], valid_b,
+                                       frozen)
         q_out[i + 1] = queue
         choice_out[0, i] = choice
         lat_out[0, i] = m

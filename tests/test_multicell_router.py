@@ -64,12 +64,16 @@ def _random_stream(rng, n, n_cells, rate=500.0):
     )
 
 
-def _run_scalar(fleet, models, bits, toks, cells, arrivals):
+def _run_scalar(fleet, models, bits, toks, cells, arrivals, **knobs):
+    """The scalar oracle over the stream; ``knobs`` are optional
+    per-request ``Request`` columns (``deadline_s``, ``eta``, ...)."""
     router = ModelAwareRouter(copy.deepcopy(fleet), CATALOG)
     choices, lats = [], []
-    for m, b, t, c, a in zip(models, bits, toks, cells, arrivals):
+    for i, (m, b, t, c, a) in enumerate(
+            zip(models, bits, toks, cells, arrivals)):
         ch, l = router.route(
-            Request(int(m), float(b), int(t), cell=int(c), arrival_s=float(a))
+            Request(int(m), float(b), int(t), cell=int(c), arrival_s=float(a),
+                    **{k: float(v[i]) for k, v in knobs.items()})
         )
         choices.append(ch)
         lats.append(l)
@@ -445,13 +449,19 @@ def test_orphan_cell_requests_are_rejected_uncommitted():
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
-@pytest.mark.parametrize("seed,n_cells,chunk,cache_slots,cloud", [
-    (50, 1, 64, 2, True),    # hit-heavy: whole chunks commit speculatively
-    (51, 2, 100, 1, False),  # slots=1 + orphan cells: constant conflicts
-    (52, 4, 64, 1, True),    # miss-heavy with cloud + drain
+@pytest.mark.parametrize("seed,n_cells,chunk,cache_slots,cloud,knobs", [
+    # hit-heavy: whole chunks commit speculatively
+    pytest.param(50, 1, 64, 2, True, False, id="50-1-64-2-True"),
+    # slots=1 + orphan cells: constant conflicts
+    pytest.param(51, 2, 100, 1, False, False, id="51-2-100-1-False"),
+    # miss-heavy with cloud + drain
+    pytest.param(52, 4, 64, 1, True, False, id="52-4-64-1-True"),
+    # misses replayed up to a padded last chunk, under an SLO deadline
+    # column and a partial offload priced on the device (eta + local)
+    pytest.param(53, 2, 64, 1, False, True, id="53-2-64-1-False-slo-eta"),
 ])
 def test_speculative_commit_matches_scalar_oracle(seed, n_cells, chunk,
-                                                  cache_slots, cloud,
+                                                  cache_slots, cloud, knobs,
                                                   backend):
     """The speculative parallel commit reproduces the scalar oracle —
     choices, LRU clocks, residency, queues and fleet clock — for C in
@@ -460,9 +470,10 @@ def test_speculative_commit_matches_scalar_oracle(seed, n_cells, chunk,
     mutating commit (a conflict) in essentially every chunk, so the
     serial suffix replay is exercised, not just the all-hit fast path;
     the no-cloud config streams orphan cells so rejected requests flow
-    through the speculative recurrence too. The speculative path must
-    also equal the plain correction scan bit for bit (latencies
-    included), not merely to ulps."""
+    through the speculative recurrence too, and the ``knobs`` config
+    runs the replay through the deadline, eta and local-rate columns.
+    The speculative path must also equal the plain correction scan bit
+    for bit (latencies included), not merely to ulps."""
     with enable_x64():
         rng = np.random.default_rng(seed)
         fleet = _random_multicell_fleet(rng, n_cells, 3,
@@ -471,8 +482,16 @@ def test_speculative_commit_matches_scalar_oracle(seed, n_cells, chunk,
         models, bits, toks, cells, arrivals = _random_stream(
             rng, 250, n_cells if cloud else n_cells + 1
         )
+        cols = {}
+        if knobs:  # tight / loose / no SLO; quarter offload ratios
+            krng = np.random.default_rng([seed, 1])
+            cols = dict(
+                deadline_s=krng.choice([0.05, 5.0, np.inf], size=250),
+                eta=krng.choice([0.0, 0.25, 0.5, 0.75, 1.0], size=250),
+                local_flops_per_s=krng.uniform(5e11, 5e12, 250),
+            )
         router, sc_choice, sc_lat = _run_scalar(
-            fleet, models, bits, toks, cells, arrivals
+            fleet, models, bits, toks, cells, arrivals, **cols
         )
         params, state0 = br.fleet_from_servers(fleet, CATALOG)
         reqs = br.RequestBatch(
@@ -481,6 +500,7 @@ def test_speculative_commit_matches_scalar_oracle(seed, n_cells, chunk,
             gen_tokens=jnp.asarray(toks, jnp.float64),
             cell=jnp.asarray(cells, jnp.int32),
             arrival_s=jnp.asarray(arrivals, jnp.float64),
+            **{k: jnp.asarray(v, jnp.float64) for k, v in cols.items()},
         )
         st_spec, out_spec = br.route_batch(params, state0, reqs, chunk=chunk,
                                            backend=backend, speculative=True)
@@ -488,9 +508,15 @@ def test_speculative_commit_matches_scalar_oracle(seed, n_cells, chunk,
                                          backend=backend, speculative=False)
         if not cloud:  # the orphan cells actually exercised rejection
             assert (sc_choice == -1).any()
+        if knobs:  # the deadlines rejected some, and misses were replayed
+            assert (sc_choice == -1).any()
+            assert (~np.asarray(out_spec.hit) & (sc_choice >= 0)).any()
         np.testing.assert_array_equal(np.asarray(out_spec.choice), sc_choice)
-        np.testing.assert_allclose(np.asarray(out_spec.latency), sc_lat,
-                                   rtol=1e-12, atol=0.0)
+        # an SLO rejection reports its best score, where the oracle
+        # reports inf: latencies are compared where the oracle routed
+        done = sc_choice >= 0 if knobs else slice(None)
+        np.testing.assert_allclose(np.asarray(out_spec.latency)[done],
+                                   sc_lat[done], rtol=1e-12, atol=0.0)
         _assert_fleet_state_matches(router, st_spec)
         # speculative vs serial correction scan: bit-identical
         np.testing.assert_array_equal(np.asarray(out_spec.choice),
